@@ -25,19 +25,6 @@ from repro.core.connectors.base import (
 )
 
 
-def _open_segment(
-    name: str, *, create: bool = False, size: int = 0
-) -> shared_memory.SharedMemory:
-    """Create/attach a segment; ``track=False`` (no resource-tracker unlink
-    races across processes) exists only on Python >= 3.13."""
-    try:
-        return shared_memory.SharedMemory(
-            name=name, create=create, size=size, track=False
-        )
-    except TypeError:
-        return shared_memory.SharedMemory(name=name, create=create, size=size)
-
-
 @register_connector("shm")
 class SharedMemoryConnector:
     #: Same-host consumers can attach a published segment by ref and read
@@ -67,7 +54,9 @@ class SharedMemoryConnector:
         frames = [memoryview(f).cast("B") for f in payload_frames(data)]
         total = sum(f.nbytes for f in frames) or 1
         key = Key.new()
-        seg = _open_segment(self._name(key.object_id), create=True, size=total)
+        seg = shared_memory.SharedMemory(
+            self._name(key.object_id), create=True, size=total
+        )
         off = 0
         for f in frames:
             seg.buf[off : off + f.nbytes] = f
@@ -85,13 +74,14 @@ class SharedMemoryConnector:
         Only a size mismatch (impure recompute) unlinks and recreates."""
         frames = [memoryview(f).cast("B") for f in payload_frames(data)]
         total = sum(f.nbytes for f in frames) or 1
+        name = self._name(key.object_id)
         try:
-            seg = _open_segment(self._name(key.object_id), create=True, size=total)
+            seg = shared_memory.SharedMemory(name, create=True, size=total)
         except FileExistsError:
             seg = self._attach(key)
             if seg is None or seg.size < total:
                 self.evict(key)
-                seg = _open_segment(self._name(key.object_id), create=True, size=total)
+                seg = shared_memory.SharedMemory(name, create=True, size=total)
         off = 0
         for f in frames:
             seg.buf[off : off + f.nbytes] = f
@@ -117,7 +107,7 @@ class SharedMemoryConnector:
         if seg is not None:
             return seg
         try:
-            seg = _open_segment(self._name(key.object_id))
+            seg = shared_memory.SharedMemory(self._name(key.object_id))
         except FileNotFoundError:
             return None
         with self._lock:

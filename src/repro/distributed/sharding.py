@@ -24,26 +24,10 @@ from jax.sharding import PartitionSpec as P
 
 
 def abstract_mesh(shape: tuple[int, ...], names: tuple[str, ...]):
-    """Version-compat ``AbstractMesh`` constructor.
-
-    jax <= 0.4.x takes a single ``((name, size), ...)`` shape tuple; newer
-    releases take ``(shape, names)``.  Try both and verify the axis names
-    landed, since the old signature silently accepts two positionals.
-    """
+    """A device-free mesh of ``shape`` with axis ``names`` (for rule tests)."""
     from jax.sharding import AbstractMesh
 
-    last_exc: Exception | None = None
-    for args in ((tuple(zip(names, shape)),), (tuple(shape), tuple(names))):
-        try:
-            mesh = AbstractMesh(*args)
-            if tuple(mesh.axis_names) == tuple(names):
-                return mesh
-        except (TypeError, ValueError) as exc:
-            last_exc = exc
-    raise TypeError(
-        f"could not construct AbstractMesh(shape={shape}, names={names}) "
-        f"with jax {jax.__version__}"
-    ) from last_exc
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def _axsize(mesh: Mesh, axis) -> int:

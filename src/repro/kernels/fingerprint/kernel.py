@@ -42,7 +42,7 @@ def _fp_kernel(x_ref, out_ref, acc_ref, *, n_blocks: int):
         out_ref[0, :, :] = acc_ref[...]
 
 
-def fingerprint_blocks(blocks: jax.Array, *, interpret: bool = True) -> jax.Array:
+def fingerprint_blocks(blocks: jax.Array, *, interpret: bool) -> jax.Array:
     """blocks: (n_blocks, 8, 128) uint32 -> folded (2,) uint32 token."""
     nb = blocks.shape[0]
     kernel = functools.partial(_fp_kernel, n_blocks=nb)

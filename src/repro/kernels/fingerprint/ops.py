@@ -8,11 +8,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.fingerprint.ref import BLOCK_BYTES
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -21,7 +18,7 @@ def fingerprint(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     from repro.kernels.fingerprint.kernel import fingerprint_blocks
 
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     flat = jax.lax.bitcast_convert_type(
         x.reshape(-1), jnp.uint8
     ).reshape(-1) if x.dtype != jnp.uint8 else x.reshape(-1)

@@ -114,7 +114,7 @@ def flash_attention_bh(
     kv_len: int,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Pallas call over flattened (batch*head) leading dims; inputs padded."""
     BH, Sq, hd = q.shape

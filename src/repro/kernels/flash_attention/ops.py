@@ -1,8 +1,8 @@
 """Jit'd public wrapper for the flash-attention Pallas kernel.
 
-Handles layout (B, H, S, hd) <-> kernel layout, GQA head mapping, padding to
-block multiples, and CPU-interpret fallback (``interpret=True`` executes the
-kernel body in Python -- bit-for-bit the algorithm that runs on TPU).
+Handles layout (B, H, S, hd) <-> kernel layout, GQA head mapping and padding
+to block multiples.  ``interpret=True`` executes the kernel body in Python --
+the same algorithm that runs compiled on the TPU.
 """
 
 from __future__ import annotations
@@ -11,6 +11,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import interpret_mode
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -21,10 +23,6 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -52,7 +50,7 @@ def flash_attention_gqa(
     if scale is None:
         scale = hd**-0.5
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
 
     bq = min(block_q, max(8, 1 << (Sq - 1).bit_length()))
     bk = min(block_k, max(8, 1 << (Skv - 1).bit_length()))

@@ -13,9 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -35,17 +33,17 @@ def ssd_scan(
     B, S, H, P = x.shape
     N = b.shape[-1]
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     Q = min(chunk, max(8, 1 << (S - 1).bit_length()))
     pad = (-S) % Q
 
     xf = x.transpose(0, 2, 1, 3).reshape(B * H, S, P)
-    af = a.transpose(0, 2, 1).reshape(B * H, S)
+    af = a.transpose(0, 2, 1).reshape(B * H, 1, S)
     bf = b.transpose(0, 2, 1, 3).reshape(B * H, S, N)
     cf = c.transpose(0, 2, 1, 3).reshape(B * H, S, N)
     if pad:
         xf = jnp.pad(xf, ((0, 0), (0, pad), (0, 0)))
-        af = jnp.pad(af, ((0, 0), (0, pad)))
+        af = jnp.pad(af, ((0, 0), (0, 0), (0, pad)))
         bf = jnp.pad(bf, ((0, 0), (0, pad), (0, 0)))
         cf = jnp.pad(cf, ((0, 0), (0, pad), (0, 0)))
 

@@ -3,11 +3,12 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 )
+os.environ["JAX_PLATFORMS"] = "cpu"  # 512 fake host devices; never the chip
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: the production
-mesh is built from 512 forced host devices (the two lines above MUST run
+mesh is built from 512 forced CPU devices (the lines above MUST run
 before any other import), every cell's step is jitted with explicit in/out
 shardings, compiled, and its memory/cost/collective profile is written to
 ``artifacts/dryrun/<arch>__<shape>__<mesh>.json``.

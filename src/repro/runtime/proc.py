@@ -40,6 +40,9 @@ from repro.runtime.comm import ChannelClosed, Comm, connect, listen
 
 _SPAWN = mp.get_context("spawn")
 
+#: Serializes the parent-environment swap around a child's spawn.
+_SPAWN_ENV_LOCK = threading.Lock()
+
 #: How long an accepted connection may take to send its REGISTER.
 _HANDSHAKE_TIMEOUT = 30.0
 
@@ -317,7 +320,23 @@ class ProcessWorker:
         )
 
     def start(self) -> "ProcessWorker":
-        self._proc.start()
+        """Spawn the child with ``JAX_PLATFORMS=cpu``.
+
+        An accelerator belongs to one process at a time: the parent that
+        holds it makes a child's jax fail or hang, so workers run jax on
+        the CPU.  The child inherits the environment as it is while
+        ``start`` runs, before it imports anything.
+        """
+        with _SPAWN_ENV_LOCK:
+            prev = os.environ.get("JAX_PLATFORMS")
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            try:
+                self._proc.start()
+            finally:
+                if prev is None:
+                    del os.environ["JAX_PLATFORMS"]
+                else:
+                    os.environ["JAX_PLATFORMS"] = prev
         return self
 
     @property

@@ -26,7 +26,12 @@ def synthetic_batch(
     vocab: int,
     extras: dict[str, tuple] | None = None,
 ) -> dict[str, np.ndarray]:
-    out = {"tokens": rng.integers(0, vocab, (batch, seq), dtype=np.int32)}
+    """Token ids drawn from a Zipf law over the vocabulary, p(k) ~ 1/(k+1):
+    the shape of natural-language token frequencies.  Unlike uniform ids it
+    can be learned, so the loss of even a few steps falls."""
+    p = 1.0 / np.arange(1, vocab + 1)
+    tokens = rng.choice(vocab, size=(batch, seq), p=p / p.sum())
+    out = {"tokens": tokens.astype(np.int32)}
     for name, shape in (extras or {}).items():
         out[name] = rng.standard_normal(shape, dtype=np.float32)
     return out

@@ -367,6 +367,12 @@ def _slow_echo(x, delay=0.3):
     return x
 
 
+def _jax_platforms():
+    import os
+
+    return os.environ.get("JAX_PLATFORMS")
+
+
 def _process_cluster(n_workers=2, **kw):
     from repro.api import ClusterSpec
 
@@ -390,6 +396,21 @@ def test_process_cluster_submit_gather():
 
         pids = {w.pid for w in cluster.workers.values()}
         assert os.getpid() not in pids and len(pids) == 2
+
+
+@pytest.mark.slow
+def test_process_workers_stay_off_the_accelerator(monkeypatch):
+    """Spawned workers run jax on the CPU whatever the parent's platform:
+    the parent may hold the one chip.  The parent's environment is left
+    as it was."""
+    import os
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with _process_cluster(1) as cluster:
+        cluster.wait_for_workers(timeout=90)
+        client = cluster.get_client()
+        assert client.submit(_jax_platforms).result(timeout=120) == "cpu"
+    assert os.environ["JAX_PLATFORMS"] == "tpu"
 
 
 @pytest.mark.slow
