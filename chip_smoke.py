@@ -123,7 +123,7 @@ def phase_serve() -> None:
     import numpy as np
 
     from repro.configs import get_config
-    from repro.launch.serve import parse_args, serve
+    from repro.launch.serve import parse_args, serve, stage_line
 
     arch, gen, n_req = "qwen2.5-3b", 16, 8
     out = serve(parse_args([
@@ -140,8 +140,8 @@ def phase_serve() -> None:
             raise RuntimeError(f"bad generation: shape {toks.shape}, "
                                f"range [{toks.min()}, {toks.max()}]")
     print(f"[serve] {arch} full width: {n_req}/{n_req} requests ok | "
-          f"compile {out['compile_s']:.1f}s | decode {out['decode_tok_s']:.1f} tok/s "
-          f"| peak {_peak_bytes(jax.devices()[0])} B", flush=True)
+          f"compile {out['compile_s']:.1f}s | peak {_peak_bytes(jax.devices()[0])} B | "
+          f"{stage_line(out['server'], out['stream']['topics'])}", flush=True)
 
 
 def _run_dir(name: str) -> str:

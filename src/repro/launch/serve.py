@@ -98,31 +98,25 @@ def serve(args) -> dict:
     ).compile()
     del toks0, cache0
     compile_s = time.perf_counter() - t0
-    timings = {"prefill_s": 0.0, "decode_s": 0.0, "decoded": 0}
 
     def generate(prompts: list) -> list:
         """Batched forward for the server: pad to the fixed serving width
-        (one compiled program), prefill once, step the KV cache."""
+        (one compiled program), prefill once, step the KV cache.  The host
+        enqueues every step without waiting for the device; the batch's
+        service time is ``ModelServer.stats()``'s."""
         k = len(prompts)
         toks = np.stack([np.asarray(p, np.int32) for p in prompts])
         if k < B:
             toks = np.concatenate([toks, np.zeros((B - k, PL), np.int32)])
         cache = tx.init_cache(cfg, B, PL + G + 1)
-        t0 = time.perf_counter()
         logits, cache = prefill(params, jnp.asarray(toks), cache)
-        jax.block_until_ready(logits)
-        timings["prefill_s"] += time.perf_counter() - t0
         tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
         out = [tok]
-        t0 = time.perf_counter()
         for i in range(G - 1):
             pos = jnp.full((B, 1), PL + i, jnp.int32)
             logits, cache = decode(params, cache, tok, pos)
             tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
             out.append(tok)
-        jax.block_until_ready(tok)
-        timings["decode_s"] += time.perf_counter() - t0
-        timings["decoded"] += k * (G - 1)
         full = np.asarray(jnp.concatenate(out, axis=1))
         return [full[i] for i in range(k)]
 
@@ -160,23 +154,28 @@ def serve(args) -> dict:
         raise RuntimeError(
             f"served {len(outs)}/{n_req} requests; first failures: {failures[:3]}"
         )
-    tps = timings["decoded"] / timings["decode_s"] if timings["decode_s"] else 0.0
     print(f"served {n_req} reqs in {sstats['batches']} batches "
-          f"(mean {sstats['mean_batch']:.2f}) | compile {compile_s:.1f}s "
-          f"| prefill {timings['prefill_s']:.3f}s | decode {tps:,.1f} tok/s")
-    print(f"latency p50/p99: {sstats['latency_p50_ms']:.1f}/"
-          f"{sstats['latency_p99_ms']:.1f} ms | broker {hub['broker_bytes']:,}B "
-          f"vs payload {hub['payload_bytes']:,}B")
+          f"(mean {sstats['mean_batch']:.2f}) | compile {compile_s:.1f}s")
+    print(stage_line(sstats, hub["topics"]))
+    print(f"broker {hub['broker_bytes']:,}B vs payload {hub['payload_bytes']:,}B")
     return {
         "outputs": outs,
         "compile_s": compile_s,
-        "prefill_s": timings["prefill_s"],
-        "decode_tok_s": tps,
         "requests": n_req,
         "wall_s": t_wall,
         "server": sstats,
         "stream": hub,
     }
+
+
+def stage_line(server: dict, topics: dict) -> str:
+    """A request's stages as ``ModelServer.stats()`` and the stream topics'
+    delivery counters give them (means, ms)."""
+    hop = {t: topics.get(t, {}).get("deliver_mean_ms", 0.0) for t in ("requests", "responses")}
+    return (f"request hop {hop['requests']:.2f} | queue {server['queue_mean_ms']:.1f} "
+            f"| service {server['service_mean_ms']:.1f} (p50 {server['service_p50_ms']:.1f}) "
+            f"| emit {server['emit_mean_ms']:.2f} | reply hop {hop['responses']:.2f} ms "
+            f"| batch turnaround p50 {server['turnaround_p50_ms']:.2f} ms")
 
 
 def parse_args(argv=None):

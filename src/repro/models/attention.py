@@ -260,11 +260,13 @@ def apply_attention(
             out = chunked_attention(
                 q, k, v, causal=True, window=window, chunk=cfg.attention_chunk
             )
-            new_cache = _fill_ring_cache(cache, k, v)
+            with jax.named_scope("kv_write"):
+                new_cache = _fill_ring_cache(cache, k, v)
         elif cache is not None:
-            k, v, new_cache, kv_len, q_offset, cache_causal = _update_kv_cache(
-                cache, k, v, positions, window, aligned=cfg.aligned_decode
-            )
+            with jax.named_scope("kv_write"):
+                k, v, new_cache, kv_len, q_offset, cache_causal = _update_kv_cache(
+                    cache, k, v, positions, window, aligned=cfg.aligned_decode
+                )
             q = q.reshape(B, S, KV, G, hd)
             out = chunked_attention(
                 q, k, v,
@@ -402,10 +404,11 @@ def apply_mla(
         # decode: absorbed formulation against the latent cache
         length = cache["length"]
         size = cache["c"].shape[1]
-        write_pos = (length[:, None] + jnp.arange(S)) % size
-        bidx = jnp.arange(B)[:, None]
-        c_all = cache["c"].at[bidx, write_pos].set(c)
-        kr_all = cache["k_rope"].at[bidx, write_pos].set(k_rope)
+        with jax.named_scope("kv_write"):
+            write_pos = (length[:, None] + jnp.arange(S)) % size
+            bidx = jnp.arange(B)[:, None]
+            c_all = cache["c"].at[bidx, write_pos].set(c)
+            kr_all = cache["k_rope"].at[bidx, write_pos].set(k_rope)
         new_len = length + S
         new_cache = {"c": c_all, "k_rope": kr_all, "length": new_len}
 

@@ -102,9 +102,11 @@ def init_embedding(cfg, key) -> Params:
 
 
 def embed_tokens(cfg, p: Params, tokens: jax.Array) -> jax.Array:
-    return p["embed"].astype(cfg.compute_dtype)[tokens]
+    with jax.named_scope("embed"):
+        return p["embed"].astype(cfg.compute_dtype)[tokens]
 
 
 def logits_matmul(cfg, p: Params, x: jax.Array) -> jax.Array:
-    w = p.get("unembed", p["embed"]).astype(cfg.compute_dtype)
-    return x @ w.T
+    with jax.named_scope("logits"):
+        w = p.get("unembed", p["embed"]).astype(cfg.compute_dtype)
+        return x @ w.T

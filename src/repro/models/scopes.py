@@ -1,0 +1,80 @@
+"""The model step's named scopes, and which scope each op of a compiled
+program belongs to.
+
+The models mark their parts with ``jax.named_scope``, which is compile-time
+metadata only: it changes each op's ``op_name`` and nothing else.
+
+- ``embed``: the embedding lookup.
+- ``attn``: a layer's pre-norm, projections, rotary embedding, cache write,
+  attention core, output projection and residual add; the cache write is
+  also under ``attn/kv_write``.  A hybrid layer's mix of its attention and
+  SSM heads is ``attn`` too.
+- ``mlp``, ``moe``: the second norm, the MLP or expert layer, the residual.
+- ``ssm``: the Mamba mixer (with its norm and residual in an SSM stack).
+- ``logits``: the final norm and the output head.
+
+``op_scopes`` reads a compiled program's ``as_text()``: each instruction
+maps to the innermost of those scopes in its ``op_name``; a fusion that has
+none takes the scope of the instructions it fuses.  An instruction with no
+scope inside a while loop is the layer scan's own slicing and stacking of
+weights and caches (``loop``); anything else is ``other``.  The instruction
+names are those of the device ops in a profiler trace, so the map splits a
+program's device time by scope.
+
+JAX's persistent compilation cache leaves ``op_name`` out of its key, so a
+program built with scopes can be handed the executable of an earlier build
+without them; ``op_scopes`` raises on a program whose matrix products carry
+no scope rather than count all of it as ``loop`` and ``other``.
+"""
+
+from __future__ import annotations
+
+import re
+
+SCOPES = ("embed", "attn", "mlp", "moe", "ssm", "logits")
+LOOP, OTHER = "loop", "other"
+
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s")
+_CALLS = re.compile(r"calls=%([\w.\-]+)")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+# A scope is a whole component of the name stack, possibly wrapped by a
+# transformation: ``.../attn/...``, ``jvp(mlp)``, ``transpose(jvp(attn))``.
+_SCOPE = re.compile(r"(?:^|[/(])(" + "|".join(SCOPES) + r")(?=[/)]|$)")
+_LOOP = re.compile(r"(?:^|/)while/(?:body|cond)(?:/|$)")
+_PRODUCT = re.compile(r"=\s*\S+\s+(?:dot|convolution)\(")
+
+
+def scope_of(op_name: str) -> str:
+    """The scope of one ``op_name`` from a compiled program's metadata."""
+    found = _SCOPE.findall(op_name)
+    if found:
+        return found[-1]
+    return LOOP if _LOOP.search(op_name) else OTHER
+
+
+def op_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: scope}`` for every instruction of a compiled
+    program's text (``jax.stages.Compiled.as_text()``)."""
+    scopes: dict[str, str] = {}
+    fused: dict[str, str] = {}  # unnamed instruction -> the computation it calls
+    first: dict[str, str] = {}  # computation -> the scope of its first scoped instruction
+    comp = ""
+    for line in hlo_text.splitlines():
+        if head := _COMPUTATION.match(line):
+            comp = head.group(1)
+        elif m := _INSTR.match(line):
+            op_name = _OP_NAME.search(line)
+            scope = scopes[m.group(1)] = scope_of(op_name.group(1)) if op_name else OTHER
+            if scope != OTHER:
+                first.setdefault(comp, scope)
+            elif not op_name and (calls := _CALLS.search(line)):
+                fused[m.group(1)] = calls.group(1)
+    for name, callee in fused.items():
+        scopes[name] = first.get(callee, OTHER)
+    if _PRODUCT.search(hlo_text) and not set(SCOPES) & set(scopes.values()):
+        raise ValueError(
+            "the program's matrix products carry no named scope: it was built "
+            "without them, or loaded from a compile-cache entry that was"
+        )
+    return scopes

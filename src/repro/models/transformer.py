@@ -139,54 +139,69 @@ def _apply_layer(
     cache: Params | None,
     ctx: RunCtx,
 ) -> tuple[jax.Array, Params | None, jax.Array]:
-    """Returns (x_out, new_cache, aux_loss)."""
+    """Returns (x_out, new_cache, aux_loss).
+
+    Each block runs under a ``jax.named_scope`` (``attn``, ``mlp``, ``moe``,
+    ``ssm``; ``repro.models.scopes``) that holds its norm, its body and its
+    residual add, so every op of the layer loop outside them is the scan's
+    own slicing and stacking.
+    """
     aux = jnp.zeros((), jnp.float32)
     if group.kind == "ssm":
-        h = apply_norm(cfg, p["ln1"], x)
-        y, new_cache = ssm_mod.apply_mamba(cfg, p["mamba"], h, cache=cache, ctx=ctx)
-        return x + y, new_cache, aux
+        with jax.named_scope("ssm"):
+            h = apply_norm(cfg, p["ln1"], x)
+            y, new_cache = ssm_mod.apply_mamba(cfg, p["mamba"], h, cache=cache, ctx=ctx)
+            return x + y, new_cache, aux
 
-    h = apply_norm(cfg, p["ln1"], x)
     new_cache: Params = {}
     if group.kind == "hybrid":
         a_cache = cache.get("attn") if cache else None
         s_cache = cache.get("ssm") if cache else None
-        y_attn, a_new = attn_mod.apply_attention(
-            cfg, p["attn"], h, positions=positions, causal=True,
-            window=group.window, cache=a_cache, ctx=ctx,
-        )
-        y_ssm, s_new = ssm_mod.apply_mamba(cfg, p["mamba"], h, cache=s_cache, ctx=ctx)
-        ct = cfg.compute_dtype
-        y = 0.5 * (
-            y_attn * p["beta_attn"].astype(ct) + y_ssm * p["beta_ssm"].astype(ct)
-        )
-        x = x + y
-        h2 = apply_norm(cfg, p["ln2"], x)
-        x = x + apply_mlp(cfg, p["mlp"], h2)
+        with jax.named_scope("attn"):
+            h = apply_norm(cfg, p["ln1"], x)
+            y_attn, a_new = attn_mod.apply_attention(
+                cfg, p["attn"], h, positions=positions, causal=True,
+                window=group.window, cache=a_cache, ctx=ctx,
+            )
+        with jax.named_scope("ssm"):
+            y_ssm, s_new = ssm_mod.apply_mamba(cfg, p["mamba"], h, cache=s_cache, ctx=ctx)
+        with jax.named_scope("attn"):  # the two heads' mix and the residual
+            ct = cfg.compute_dtype
+            y = 0.5 * (
+                y_attn * p["beta_attn"].astype(ct) + y_ssm * p["beta_ssm"].astype(ct)
+            )
+            x = x + y
+        with jax.named_scope("mlp"):
+            h2 = apply_norm(cfg, p["ln2"], x)
+            x = x + apply_mlp(cfg, p["mlp"], h2)
         if cache is not None:
             new_cache = {"attn": a_new, "ssm": s_new}
         return x, (new_cache if cache is not None else None), aux
 
-    if cfg.mla is not None:
-        y, a_new = attn_mod.apply_mla(
-            cfg, p["attn"], h, positions=positions, cache=cache, ctx=ctx
-        )
-    else:
-        y, a_new = attn_mod.apply_attention(
-            cfg, p["attn"], h, positions=positions, causal=True,
-            window=group.window, cache=cache, ctx=ctx,
-        )
-    x = x + y
-    h2 = apply_norm(cfg, p["ln2"], x)
+    with jax.named_scope("attn"):
+        h = apply_norm(cfg, p["ln1"], x)
+        if cfg.mla is not None:
+            y, a_new = attn_mod.apply_mla(
+                cfg, p["attn"], h, positions=positions, cache=cache, ctx=ctx
+            )
+        else:
+            y, a_new = attn_mod.apply_attention(
+                cfg, p["attn"], h, positions=positions, causal=True,
+                window=group.window, cache=cache, ctx=ctx,
+            )
+        x = x + y
     if group.kind == "moe":
-        y2, aux = moe_mod.apply_moe(
-            cfg, p["moe"], h2,
-            mesh=ctx.mesh, dp_axes=ctx.dp_axes, ep_axis=ctx.ep_axis,
-            decode=ctx.decode,
-        )
-    else:
-        y2 = apply_mlp(cfg, p["mlp"], h2)
-    return x + y2, a_new, aux
+        with jax.named_scope("moe"):
+            h2 = apply_norm(cfg, p["ln2"], x)
+            y2, aux = moe_mod.apply_moe(
+                cfg, p["moe"], h2,
+                mesh=ctx.mesh, dp_axes=ctx.dp_axes, ep_axis=ctx.ep_axis,
+                decode=ctx.decode,
+            )
+            return x + y2, a_new, aux
+    with jax.named_scope("mlp"):
+        h2 = apply_norm(cfg, p["ln2"], x)
+        return x + apply_mlp(cfg, p["mlp"], h2), a_new, aux
 
 
 def _remat_wrap(cfg: ModelConfig, fn: Callable) -> Callable:
@@ -279,7 +294,8 @@ def forward(
         aux_total = aux_total + aux
         if cache is not None:
             new_cache[group.name] = gnew
-    x = apply_norm(cfg, params["final_norm"], x)
+    with jax.named_scope("logits"):
+        x = apply_norm(cfg, params["final_norm"], x)
     return x, (new_cache if cache is not None else None), aux_total
 
 

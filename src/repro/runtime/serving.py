@@ -17,7 +17,15 @@ latency distribution bounded instead of growing an unbounded backlog.
 
 Per-request latency (queue wait and total) is recorded and surfaced via
 ``stats()`` as p50/p99, which is what ``benchmarks/serving.py`` reports
-for the batched-vs-unbatched comparison.
+for the batched-vs-unbatched comparison.  ``stats()`` also splits a
+request's time on the server into stages: queue (submit to ``model_fn``
+call), service (the ``model_fn`` call) and emit (``model_fn`` return to the
+moment its result is handed on, which for an attached stream is the reply's
+send); and per batch, the batcher's turnaround between two ``model_fn``
+calls.  The stream hops on either side of the server are the topics'
+delivery times (:meth:`~repro.runtime.stream.StreamHub.stats`).  The
+batcher's stages are also host spans on a profiler trace
+(:mod:`repro.runtime.telemetry`).
 
 ``attach(consumer, producer)`` pumps a request stream through the server
 and emits responses to a reply stream, so the whole service composes out
@@ -28,6 +36,7 @@ sole place where bytes are actually materialized for the forward pass.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -35,24 +44,15 @@ from concurrent.futures import Future
 from typing import Any, Callable, Sequence
 
 from repro.runtime.stream import EndOfStream, StreamClosed
-
-_LAT_WINDOW = 4096  # per-request latency samples kept for percentiles
+from repro.runtime.telemetry import SAMPLE_WINDOW, mean, percentile, span
 
 
 class ServerOverloaded(RuntimeError):
     """Admission queue full: the request was shed, not enqueued."""
 
 
-def _percentile(samples: Sequence[float], q: float) -> float:
-    if not samples:
-        return 0.0
-    xs = sorted(samples)
-    idx = min(len(xs) - 1, max(0, round(q * (len(xs) - 1))))
-    return xs[idx]
-
-
 class _Request:
-    __slots__ = ("payload", "metadata", "future", "t_submit", "t_start")
+    __slots__ = ("payload", "metadata", "future", "t_submit", "t_start", "t_emit")
 
     def __init__(self, payload: Any, metadata: dict[str, Any]):
         self.payload = payload
@@ -60,6 +60,7 @@ class _Request:
         self.future: Future = Future()
         self.t_submit = time.monotonic()
         self.t_start = 0.0
+        self.t_emit = 0.0
 
 
 class ModelServer:
@@ -95,8 +96,11 @@ class ModelServer:
         self._rejected = 0
         self._batches = 0
         self._batched_requests = 0
-        self._queue_ms: deque[float] = deque(maxlen=_LAT_WINDOW)
-        self._total_ms: deque[float] = deque(maxlen=_LAT_WINDOW)
+        self._queue_ms: deque[float] = deque(maxlen=SAMPLE_WINDOW)
+        self._service_ms: deque[float] = deque(maxlen=SAMPLE_WINDOW)
+        self._emit_ms: deque[float] = deque(maxlen=SAMPLE_WINDOW)
+        self._total_ms: deque[float] = deque(maxlen=SAMPLE_WINDOW)
+        self._turnaround_ms: deque[float] = deque(maxlen=SAMPLE_WINDOW)
 
         self._pumps: list[threading.Thread] = []
         self._batcher = threading.Thread(
@@ -154,21 +158,30 @@ class ModelServer:
             return batch
 
     def _run(self) -> None:
-        while True:
-            batch = self._take_batch()
+        returned = None  # when the previous batch's model_fn returned
+        for seq in itertools.count():
+            with span("serve.take_batch", batch=seq):
+                batch = self._take_batch()
             if batch is None:
                 return
             t0 = time.monotonic()
             for req in batch:
                 req.t_start = t0
+            # The batcher's own time between two model_fn calls, counted
+            # only when a request was already waiting as the first returned.
+            turnaround = None
+            if returned is not None and batch[0].t_submit <= returned:
+                turnaround = (t0 - returned) * 1000.0
             try:
-                outputs = self.model_fn([r.payload for r in batch])
+                with span("serve.model_fn", batch=seq, size=len(batch)):
+                    outputs = self.model_fn([r.payload for r in batch])
             except BaseException as exc:  # noqa: BLE001 - fail the whole batch
+                returned = time.monotonic()
                 for req in batch:
                     req.future.set_exception(exc)
-                self._count_batch(batch, failed=True)
+                self._count_batch(batch, turnaround, failed=True)
                 continue
-            t1 = time.monotonic()
+            t1 = returned = time.monotonic()
             if len(outputs) != len(batch):
                 exc = RuntimeError(
                     f"model_fn returned {len(outputs)} outputs for a "
@@ -176,14 +189,21 @@ class ModelServer:
                 )
                 for req in batch:
                     req.future.set_exception(exc)
-                self._count_batch(batch, failed=True)
+                self._count_batch(batch, turnaround, failed=True)
                 continue
-            for req, out in zip(batch, outputs):
-                req.future.set_result(out)
-            self._count_batch(batch, t_done=t1)
+            with span("serve.emit", batch=seq):
+                for req, out in zip(batch, outputs):
+                    req.t_emit = time.monotonic()
+                    req.future.set_result(out)
+            self._count_batch(batch, turnaround, t_done=t1)
 
     def _count_batch(
-        self, batch: list[_Request], *, failed: bool = False, t_done: float = 0.0
+        self,
+        batch: list[_Request],
+        turnaround_ms: float | None,
+        *,
+        failed: bool = False,
+        t_done: float = 0.0,
     ) -> None:
         """Record a processed batch -- only after its futures resolved.
 
@@ -195,9 +215,13 @@ class ModelServer:
         with self._cond:
             self._batches += 1
             self._batched_requests += len(batch)
+            if turnaround_ms is not None:
+                self._turnaround_ms.append(turnaround_ms)
             if not failed:
                 for req in batch:
                     self._queue_ms.append((req.t_start - req.t_submit) * 1000.0)
+                    self._service_ms.append((t_done - req.t_start) * 1000.0)
+                    self._emit_ms.append((req.t_emit - t_done) * 1000.0)
                     self._total_ms.append((t_done - req.t_submit) * 1000.0)
 
     # -- stream pumping ------------------------------------------------------
@@ -251,23 +275,37 @@ class ModelServer:
     # -- telemetry / lifecycle -----------------------------------------------
 
     def stats(self) -> dict[str, float]:
+        """Counters and stage times (ms, over the last ``SAMPLE_WINDOW``
+        requests or batches).  ``latency_*`` runs from submit to
+        ``model_fn`` return."""
         with self._cond:
             queue_ms = list(self._queue_ms)
+            service_ms = list(self._service_ms)
+            emit_ms = list(self._emit_ms)
             total_ms = list(self._total_ms)
+            turnaround_ms = list(self._turnaround_ms)
             batches = self._batches
             served = self._batched_requests
-            return {
+            out: dict[str, float] = {
                 "requests": self._requests,
                 "served": served,
                 "rejected": self._rejected,
                 "batches": batches,
                 "pending": len(self._queue),
                 "mean_batch": (served / batches) if batches else 0.0,
-                "queue_p50_ms": _percentile(queue_ms, 0.50),
-                "queue_p99_ms": _percentile(queue_ms, 0.99),
-                "latency_p50_ms": _percentile(total_ms, 0.50),
-                "latency_p99_ms": _percentile(total_ms, 0.99),
             }
+        out.update(
+            queue_p50_ms=percentile(queue_ms, 0.50),
+            queue_p99_ms=percentile(queue_ms, 0.99),
+            queue_mean_ms=mean(queue_ms),
+            service_p50_ms=percentile(service_ms, 0.50),
+            service_mean_ms=mean(service_ms),
+            emit_mean_ms=mean(emit_ms),
+            latency_p50_ms=percentile(total_ms, 0.50),
+            latency_p99_ms=percentile(total_ms, 0.99),
+            turnaround_p50_ms=percentile(turnaround_ms, 0.50),
+        )
+        return out
 
     def flush(self, timeout: float = 30.0) -> None:
         """Block until every admitted request has been batched and run."""

@@ -66,6 +66,7 @@ from repro.runtime.comm import (
     encode_message,
     listen,
 )
+from repro.runtime.telemetry import SAMPLE_WINDOW, mean, percentile, span
 
 #: Default per-topic event buffer: deep enough to smooth bursts, small
 #: enough that a stalled consumer applies backpressure quickly.
@@ -456,6 +457,8 @@ class StreamHub:
         self._channels: list[CommBrokerChannel] = []
         self._payload_bytes = 0
         self._events = 0
+        self._delivered: dict[str, int] = {}
+        self._deliver_ms: dict[str, deque[float]] = {}
         self._lock = threading.Lock()
         self._closed = False
 
@@ -477,6 +480,14 @@ class StreamHub:
         with self._lock:
             self._payload_bytes += int(nbytes)
             self._events += 1
+
+    def _note_delivery(self, topic: str, ms: float) -> None:
+        with self._lock:
+            self._delivered[topic] = self._delivered.get(topic, 0) + 1
+            window = self._deliver_ms.get(topic)
+            if window is None:
+                window = self._deliver_ms[topic] = deque(maxlen=SAMPLE_WINDOW)
+            window.append(ms)
 
     # -- endpoints -----------------------------------------------------------
 
@@ -522,15 +533,31 @@ class StreamHub:
         with self._lock:
             return self._payload_bytes
 
-    def stats(self) -> dict[str, int]:
+    def stats(self) -> dict[str, Any]:
+        """Counters, and per topic that delivered an item, how long its items
+        took from the top of the producer's ``send`` to the return of the
+        consumer's ``recv``: serialize, publish, the broker, fetch and
+        deserialize (ms, over the last ``SAMPLE_WINDOW`` items; the stamp is
+        ``time.monotonic_ns``, so producer and consumer share a host)."""
         with self._lock:
             payload, events = self._payload_bytes, self._events
+            delivered = dict(self._delivered)
+            deliver_ms = {t: list(w) for t, w in self._deliver_ms.items()}
         return {
             "events": events,
             "payload_bytes": payload,
             "broker_bytes": self.broker_bytes(),
             "live_refs": len(self.ledger.live_refs()),
             "live_bytes": self.ledger.live_bytes(),
+            "topics": {
+                t: {
+                    "delivered": n,
+                    "deliver_p50_ms": percentile(deliver_ms[t], 0.50),
+                    "deliver_p95_ms": percentile(deliver_ms[t], 0.95),
+                    "deliver_mean_ms": mean(deliver_ms[t]),
+                }
+                for t, n in delivered.items()
+            },
         }
 
     # -- lifecycle -----------------------------------------------------------
@@ -635,29 +662,32 @@ class StreamProducer:
         full; ``timeout`` (default: the producer's ``send_timeout``)
         raises :class:`TimeoutError` without leaking the published bytes.
         """
+        sent_ns = time.monotonic_ns()  # the delivery time counts serialize and publish
         if self._closed:
             raise StreamClosed(f"producer for {self.topic!r} closed")
         with self._seq_lock:
             seq = self._seq
             self._seq += 1
         key = f"stream-{self.topic}-{self._uid}-{seq:08d}"
-        bundle = FrameBundle.of(serialize(value))
-        ref = self.hub.results.publish(key, bundle)
-        self.hub.ledger.track(ref, bundle.nbytes)
-        self.hub._note_payload(bundle.nbytes)
-        event = {
-            "key": key,
-            "ref": ref,
-            "nbytes": bundle.nbytes,
-            "meta": dict(metadata or {}),
-        }
-        try:
-            self._put(event, self.send_timeout if timeout is None else timeout)
-        except BaseException:
-            # The event never entered the topic: nobody will ever ack it,
-            # so release the published bytes here (exactly-once ledger).
-            self.hub.ledger.release(ref)
-            raise
+        with span("stream.send", key=key):
+            bundle = FrameBundle.of(serialize(value))
+            ref = self.hub.results.publish(key, bundle)
+            self.hub.ledger.track(ref, bundle.nbytes)
+            self.hub._note_payload(bundle.nbytes)
+            event = {
+                "key": key,
+                "ref": ref,
+                "nbytes": bundle.nbytes,
+                "meta": dict(metadata or {}),
+                "sent_ns": sent_ns,
+            }
+            try:
+                self._put(event, self.send_timeout if timeout is None else timeout)
+            except BaseException:
+                # The event never entered the topic: nobody will ever ack it,
+                # so release the published bytes here (exactly-once ledger).
+                self.hub.ledger.release(ref)
+                raise
         return key
 
     def flush(self, timeout: float = DEFAULT_SEND_TIMEOUT) -> None:
@@ -761,25 +791,27 @@ class StreamConsumer:
             self._eos = True
             raise EndOfStream(self.topic)
         ref, nbytes = event["ref"], event.get("nbytes", -1)
-        bundle = self.hub.results.fetch(ref, nbytes)
-        if bundle is None:
-            raise StreamClosed(
-                f"payload bytes for {event.get('key')} missing from the store"
+        key = event.get("key", "")
+        with span("stream.recv", key=key):
+            bundle = self.hub.results.fetch(ref, nbytes)
+            if bundle is None:
+                raise StreamClosed(f"payload bytes for {key} missing from the store")
+            value = deserialize(bundle)
+            item = StreamItem(
+                key=key,
+                value=value,
+                metadata=event.get("meta") or {},
+                nbytes=nbytes,
+                ref=ref,
+                _consumer=self,
             )
-        value = deserialize(bundle)
-        item = StreamItem(
-            key=event.get("key", ""),
-            value=value,
-            metadata=event.get("meta") or {},
-            nbytes=nbytes,
-            ref=ref,
-            _consumer=self,
-        )
-        if self.auto_ack:
-            self.ack(ref)
-        else:
-            with self._lock:
-                self._unacked.add(ref)
+            if self.auto_ack:
+                self.ack(ref)
+            else:
+                with self._lock:
+                    self._unacked.add(ref)
+        if "sent_ns" in event:
+            self.hub._note_delivery(self.topic, (time.monotonic_ns() - event["sent_ns"]) / 1e6)
         return item
 
     def ack(self, ref: str) -> bool:

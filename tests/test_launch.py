@@ -36,6 +36,11 @@ def test_serve_smoke_answers_every_request():
     assert out["requests"] == 5 and len(out["outputs"]) == 5
     for toks in out["outputs"].values():
         assert toks.shape == (4,) and 0 <= toks.min() and toks.max() < vocab
+    # the stages come from the server's and the topics' counters
+    assert out["server"]["served"] == 5 and out["server"]["service_mean_ms"] > 0.0
+    assert {t: c["delivered"] for t, c in out["stream"]["topics"].items()} == {
+        "requests": 5, "responses": 5}
+    assert "decode_tok_s" not in out and "prefill_s" not in out
 
 
 def _train_args(run_dir, steps, ckpt_every):

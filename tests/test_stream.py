@@ -9,6 +9,7 @@ and admission-control shedding.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 import uuid
@@ -98,6 +99,60 @@ def test_round_trip_wire_broker(transport):
         stats = hub.stats()
         assert stats["live_refs"] == 0
         assert stats["broker_bytes"] < stats["payload_bytes"] / 4
+
+
+@pytest.mark.parametrize("transport", [None, "inproc", "tcp"])
+def test_topic_delivery_counters(transport):
+    """Each topic counts its deliveries and times them from the top of
+    ``send`` to the return of ``recv``, on every broker substrate."""
+    with LocalCluster(n_workers=1, transport=transport) as cluster:
+        hub = cluster.streams()
+        prod, cons = hub.producer("slow"), hub.consumer("slow")
+        for i in range(5):
+            prod.send(np.arange(256) * i)
+        time.sleep(0.05)  # the items sit in the topic: that counts
+        got = [cons.recv(timeout=5) for _ in range(5)]
+        other = hub.producer("fast")
+        fast = hub.consumer("fast")
+        other.send(1)
+        fast.recv(timeout=5)
+        topics = hub.stats()["topics"]
+        assert set(topics) == {"slow", "fast"}
+        slow = topics["slow"]
+        assert slow["delivered"] == 5 and len(got) == 5
+        assert 50.0 <= slow["deliver_p50_ms"] <= slow["deliver_p95_ms"] < 5000.0
+        assert slow["deliver_mean_ms"] >= 50.0
+        assert topics["fast"]["delivered"] == 1
+        assert 0.0 < topics["fast"]["deliver_mean_ms"] < slow["deliver_mean_ms"]
+        hub.producer("never")
+        assert "never" not in hub.stats()["topics"]
+
+
+def test_delivery_counts_survive_competing_consumers(hub):
+    """Consumers racing on one topic lose no delivery count."""
+    prod = hub.producer("race", buffer=16)
+    n, workers = 400, 8
+    got = []
+
+    def drain(cons):
+        got.extend(1 for _ in cons)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=drain, args=(hub.consumer("race"),))
+                   for _ in range(workers)]
+        for t in threads:
+            t.start()
+        for i in range(n):
+            prod.send(i)
+        prod.close()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert len(got) == n and hub.stats()["topics"]["race"]["delivered"] == n
 
 
 @pytest.mark.slow
