@@ -12,7 +12,9 @@ substrate of the framework):
 * BlockSpecs tile Q as ``(1, block_q, hd)`` and K/V as ``(1, block_k, hd)``;
   with the default 128x128 blocks and hd<=256, the working set
   (q + k + v + acc + two vectors) stays well under the ~16 MB v5e VMEM
-  budget while the 128-wide dims align with the MXU systolic array.
+  budget while the 128-wide dims align with the MXU systolic array.  The
+  models call it with 512x512 blocks (``models.attention.FLASH_BLOCK``),
+  whose f32 scores and probabilities add 2 MB.
 * GQA is expressed in the K/V index maps: query head ``h`` reads kv head
   ``h // group_size`` -- no K/V duplication in HBM.
 * Causal masking skips fully-masked kv blocks via ``pl.when`` (compute is

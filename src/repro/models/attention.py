@@ -3,9 +3,12 @@
 The training/prefill path is a *chunked online-softmax* ("flash-style")
 implementation in pure jnp: both query and key/value are tiled with
 ``lax.scan`` so the S x S score matrix never materializes -- this keeps the
-dry-run memory analysis honest at 32K-512K context.  On TPU the Pallas
-kernel in ``repro.kernels.flash_attention`` replaces it (same math, MXU
-tiling) when ``cfg.attention_impl == "pallas"``.
+dry-run memory analysis honest at 32K-512K context.  The Pallas kernel in
+``repro.kernels.flash_attention`` (same math, MXU tiling) replaces it when
+``cfg.attention_impl == "pallas"``, and always in a prefill into an empty
+cache with full attention on one device (``RunCtx.empty_cache``): there it
+attends over the prompt's own keys and values, which then fill the cache
+with one slice.
 
 Note on FLOPs: the chunked reference computes masked (non-causal) blocks
 and masks them, so HLO FLOPs ~= 2x the causal-optimal count; the Pallas
@@ -177,6 +180,39 @@ def _decode_attention(q, k, v, *, causal, window, q_offset, kv_len, scale):
     return out.astype(v.dtype)
 
 
+# The flash kernel's blocks: a whole row of up to 512 queries against as many
+# keys per grid step.  At S = 512 a layer is then B * H steps (384 for
+# phi4-mini's batch of 16); the kernel's 128-wide defaults take 16 times as
+# many, each paying the step's fixed cost (on a TPU v5e, at that shape:
+# 0.86 ms a layer against 1.80 ms at 256 and 3.28 ms at 128).
+FLASH_BLOCK = 512
+
+
+def _fills_empty_cache(ctx) -> bool:
+    """The call fills an empty cache from position 0 (``RunCtx.empty_cache``)
+    on at most one device.  Under a larger mesh the prefill keeps the XLA
+    path, which SPMD partitions."""
+    if not getattr(ctx, "empty_cache", False):
+        return False
+    mesh = getattr(ctx, "mesh", None)
+    return mesh is None or mesh.size == 1
+
+
+def _flash_attention(q, k, v, *, causal: bool):
+    """Self-attention of a whole sequence from position 0 in the Pallas
+    flash kernel; model layout in and out."""
+    from repro.kernels.flash_attention.ops import flash_attention_gqa
+
+    # model layout q (B,S,KV,G,hd), k/v (B,S,KV,hd) -> kernel (B,H,S,hd)
+    B, S, KV, G, hd = q.shape
+    qk = q.transpose(0, 2, 3, 1, 4).reshape(B, KV * G, S, hd)
+    out = flash_attention_gqa(
+        qk, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+        causal=causal, block_q=FLASH_BLOCK, block_k=FLASH_BLOCK,
+    )
+    return out.reshape(B, KV, G, S, hd).transpose(0, 3, 1, 2, 4)
+
+
 def _maybe_pallas_attention(cfg, q, k, v, *, causal, window, q_offset, kv_len):
     """Dispatch to the Pallas flash kernel when configured and applicable."""
     if (
@@ -186,15 +222,7 @@ def _maybe_pallas_attention(cfg, q, k, v, *, causal, window, q_offset, kv_len):
         and isinstance(q_offset, int)
         and q_offset == 0
     ):
-        from repro.kernels.flash_attention.ops import flash_attention_gqa
-
-        # model layout q (B,S,KV,G,hd), k/v (B,S,KV,hd) -> kernel (B,H,S,hd)
-        B, S, KV, G, hd = q.shape
-        qk = q.transpose(0, 2, 3, 1, 4).reshape(B, KV * G, S, hd)
-        kk = k.transpose(0, 2, 1, 3)
-        vk = v.transpose(0, 2, 1, 3)
-        out = flash_attention_gqa(qk, kk, vk, causal=causal)
-        return out.reshape(B, KV, G, S, hd).transpose(0, 3, 1, 2, 4)
+        return _flash_attention(q, k, v, causal=causal)
     return chunked_attention(
         q, k, v,
         causal=causal, window=window, q_offset=q_offset, kv_len=kv_len,
@@ -262,6 +290,15 @@ def apply_attention(
             )
             with jax.named_scope("kv_write"):
                 new_cache = _fill_ring_cache(cache, k, v)
+        elif cache is not None and window == 0 and _fills_empty_cache(ctx):
+            # Prefill into an empty cache: the slots below S would hold
+            # exactly these keys and values and the rest are masked, so the
+            # kernel attends over them directly and the cache is not read.
+            q = q.reshape(B, S, KV, G, hd)
+            with jax.named_scope("flash"):
+                out = _flash_attention(q, k, v, causal=True)
+            with jax.named_scope("kv_write"):
+                new_cache = _fill_linear_cache(cache, k, v)
         elif cache is not None:
             with jax.named_scope("kv_write"):
                 k, v, new_cache, kv_len, q_offset, cache_causal = _update_kv_cache(
@@ -333,6 +370,15 @@ def _update_kv_cache(cache, k_new, v_new, positions, window, aligned=False):
     # Linear cache: slot index == absolute position, so causal masking with
     # q at absolute offset `length` is exact for both prefill and decode.
     return k, v, new_cache, new_len, length, True
+
+
+def _fill_linear_cache(cache, k, v):
+    """Write a prefill's keys and values into slots 0..S-1 of an empty cache."""
+    return {
+        "k": jax.lax.dynamic_update_slice(cache["k"], k, (0, 0, 0, 0)),
+        "v": jax.lax.dynamic_update_slice(cache["v"], v, (0, 0, 0, 0)),
+        "length": jnp.full_like(cache["length"], k.shape[1]),
+    }
 
 
 def _fill_ring_cache(cache, k, v):

@@ -7,8 +7,9 @@ metadata only: it changes each op's ``op_name`` and nothing else.
 - ``embed``: the embedding lookup.
 - ``attn``: a layer's pre-norm, projections, rotary embedding, cache write,
   attention core, output projection and residual add; the cache write is
-  also under ``attn/kv_write``.  A hybrid layer's mix of its attention and
-  SSM heads is ``attn`` too.
+  also under ``attn/kv_write``, and a prefill's flash kernel (with its
+  layout changes) under ``attn/flash``.  A hybrid layer's mix of its
+  attention and SSM heads is ``attn`` too.
 - ``mlp``, ``moe``: the second norm, the MLP or expert layer, the residual.
 - ``ssm``: the Mamba mixer (with its norm and residual in an SSM stack).
 - ``logits``: the final norm and the output head.

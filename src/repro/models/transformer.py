@@ -122,12 +122,18 @@ def init_params(cfg: ModelConfig, key) -> Params:
 
 @dataclasses.dataclass(frozen=True)
 class RunCtx:
-    """Per-call distribution context (mesh for EP, decode flags)."""
+    """Per-call distribution context (mesh for EP, decode flags).
+
+    ``empty_cache`` is set by :func:`prefill` alone: the cache it fills is
+    empty and the positions start at 0, so full-attention layers attend over
+    their own keys (the flash kernel) and write them with one slice.
+    """
 
     mesh: Any = None
     dp_axes: tuple[str, ...] = ("data",)
     ep_axis: str = "model"
     decode: bool = False
+    empty_cache: bool = False
 
 
 def _apply_layer(
@@ -396,9 +402,10 @@ def prefill(
     ctx: RunCtx = RunCtx(),
     patch_embeds: jax.Array | None = None,
 ) -> tuple[jax.Array, Params]:
-    """Run the full prompt through the model, filling the cache."""
+    """Run the full prompt through an empty cache, filling it."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    ctx = dataclasses.replace(ctx, empty_cache=True)
     x, new_cache, _ = forward(
         cfg, params, tokens, positions=positions, cache=cache, ctx=ctx,
         patch_embeds=patch_embeds,

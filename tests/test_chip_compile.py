@@ -12,16 +12,25 @@ library at import would make pytest-xdist workers collect different tests.
 
 from __future__ import annotations
 
+import json
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.kernels.fingerprint.ops import fingerprint
+from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.flash_attention.ops import flash_attention_gqa
 from repro.kernels.ssd_scan.ops import ssd_scan
+from repro.models import transformer as tx
+from repro.models.common import ModelConfig
+
+PHI4 = Path(__file__).resolve().parents[1] / "bench" / "configs" / "phi4-mini-3.8b.json"
 
 
 @pytest.fixture(scope="module")
@@ -82,3 +91,47 @@ def test_ssd_scan_compiles_at_model_widths(one_chip, H, N):
 def test_fingerprint_compiles_on_a_few_mib(one_chip):
     hlo = _hlo(fingerprint, [((4 << 20,), jnp.uint8)], one_chip, interpret=False)
     assert "tpu_custom_call" in hlo
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """The kernels in the mode the chip runs them in (the host here is a CPU,
+    whose default backend would pick the interpreter); traces taken in the
+    other mode are dropped on both sides."""
+    jax.clear_caches()
+    monkeypatch.setattr(flash_ops, "interpret_mode", lambda: False)
+    yield
+    jax.clear_caches()
+
+
+def test_served_prefill_runs_flash_kernel_at_phi4_mini_widths(one_chip, compiled_kernels):
+    """The benchmark's served phi4-mini-3.8b batch (16 prompts of 512 tokens,
+    641 cache slots) prefills in the flash kernel, scoped ``attn/flash``:
+    no f32 score matrix over the cache and no scatter into it.  Its decode
+    step holds no kernel."""
+    model = json.loads(PHI4.read_text())["model"]
+    cfg = ModelConfig(**{**model, "param_dtype": jnp.bfloat16, "compute_dtype": jnp.bfloat16})
+    B, S, slots = 16, 512, 641
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1), ("data", "model"))
+    ctx = tx.RunCtx(mesh=mesh, dp_axes=("data",), ep_axis="model", decode=True)
+    on_chip = NamedSharding(mesh, PartitionSpec())
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=on_chip), tree
+        )
+
+    params = shapes(jax.eval_shape(lambda k: tx.init_params(cfg, k), jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(lambda: tx.init_cache(cfg, B, slots)))
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=on_chip)
+    one = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=on_chip)
+
+    prefill = jax.jit(lambda p, t, c: tx.prefill(cfg, p, t, c, ctx))
+    hlo = prefill.lower(params, tokens, cache).compile().as_text()
+    kernels = [line for line in hlo.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all("/attn/flash/" in line for line in kernels)
+    assert f"f32[{B},{S},8,3,{slots}]" not in hlo
+    assert not re.search(r"\bscatter\(|kv_write/scatter", hlo)
+
+    decode = jax.jit(lambda p, c, t, pos: tx.decode_step(cfg, p, c, t, pos, ctx))
+    assert "tpu_custom_call" not in decode.lower(params, cache, one, one).compile().as_text()

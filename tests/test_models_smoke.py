@@ -193,6 +193,55 @@ def test_multi_step_decode_consistency(arch):
     )
 
 
+# Configurations whose full-attention layers prefill in the flash kernel.
+FLASH_PREFILL_ARCHS = ["qwen2.5-3b", "granite-20b", "hymba-1.5b", "internvl2-2b"]
+
+
+def _attn_caches(cfg, cache):
+    """Each stacked key/value cache of ``cache`` with its layer window."""
+    for group in tx.layer_groups(cfg):
+        c = cache[group.name]
+        c = c["attn"] if group.kind == "hybrid" else c
+        if "k" in c:
+            yield group, c
+
+
+@pytest.mark.parametrize("arch", FLASH_PREFILL_ARCHS)
+def test_prefill_in_flash_kernel_matches_forward_and_fills_cache(arch):
+    """A prefill into an empty cache attends over its own keys in the flash
+    kernel and writes them into slots 0..S-1: its last logits equal the
+    cache-less forward's, and its cache equals the one the general cache
+    path writes by indexing, empty from slot S on."""
+    cfg = get_smoke_config(arch)
+    rng = np.random.default_rng(13)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S)).astype(np.int32))
+    params = tx.init_params(cfg, jax.random.PRNGKey(13))
+    empty = tx.init_cache(cfg, B, S + 5)
+
+    jaxpr = str(jax.make_jaxpr(lambda p, t, c: tx.prefill(cfg, p, t, c))(params, tokens, empty))
+    assert "pallas_call" in jaxpr
+    logits, cache = tx.prefill(cfg, params, tokens, empty)
+    np.testing.assert_allclose(
+        np.asarray(logits[:, 0]), np.asarray(full_logits(cfg, params, tokens)[:, -1]),
+        rtol=1e-4, atol=1e-4,
+    )
+
+    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    _, indexed, _ = tx.forward(cfg, params, tokens, positions=positions, cache=empty)
+    for (group, got), (_, want) in zip(_attn_caches(cfg, cache), _attn_caches(cfg, indexed)):
+        np.testing.assert_array_equal(np.asarray(got["length"]), S)
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(got[name]), np.asarray(want[name]), rtol=1e-5, atol=1e-5
+            )
+            if group.window == 0:
+                assert not np.asarray(got[name])[:, :, S:].any()
+    decode = jax.make_jaxpr(lambda p, c, t, pos: tx.decode_step(cfg, p, c, t, pos))(
+        params, cache, tokens[:, :1], positions[:, :1]
+    )
+    assert "pallas_call" not in str(decode)
+
+
 def test_aligned_unrolled_decode_matches_scanned():
     """Serving fast paths (aligned_decode + unrolled layers) must be
     numerically identical to the scanned ragged-scatter path when batch
