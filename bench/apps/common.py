@@ -1,12 +1,16 @@
 """What every app shares: the run's inputs, the model configuration as the
-program takes it, and the profiler slice."""
+program takes it, the configuration's reference, and the profiler slice."""
 
 from __future__ import annotations
 
+import dataclasses
+import importlib.util
 import os
 import shutil
 import time
+import typing
 from dataclasses import dataclass, field
+from pathlib import Path
 from typing import Any
 
 import jax
@@ -21,6 +25,7 @@ class Env:
 
     cell: str
     model: dict[str, Any]          # the configuration file's ``model`` section
+    reference: str                 # path of the configuration's plain reference
     mix: dict[str, Any]            # the traffic file
     limits: dict[str, float]       # the cell's correctness limits
     seed: int
@@ -51,18 +56,37 @@ class Outcome:
 
 
 def model_config(m: dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration's ``model`` section."""
-    from repro.models.common import ModelConfig, SSMConfig
+    """The program's ``ModelConfig`` for a configuration's ``model`` section:
+    dtype names become dtypes, every section whose field is a dataclass
+    (``ssm``, ``moe``, ``mla``, ...) becomes that dataclass, lists tuples."""
+    from repro.models.common import ModelConfig
 
-    kw = dict(m)
-    for k in ("param_dtype", "compute_dtype"):
-        if k in kw:
-            kw[k] = DTYPES[kw[k]]
-    if kw.get("ssm"):
-        kw["ssm"] = SSMConfig(**kw["ssm"])
-    if "global_layers" in kw:
-        kw["global_layers"] = tuple(kw["global_layers"])
+    hints = typing.get_type_hints(ModelConfig)
+    kw = {}
+    for k, v in m.items():
+        if k in ("param_dtype", "compute_dtype"):
+            v = DTYPES[v]
+        elif isinstance(v, dict):
+            v = next(t for t in typing.get_args(hints[k]) or (hints[k],)
+                     if dataclasses.is_dataclass(t))(**v)
+        elif isinstance(v, list):
+            v = tuple(v)
+        kw[k] = v
     return ModelConfig(**kw)
+
+
+def load_module(path: str | Path, name: str):
+    """The Python module in file ``path``, loaded under ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(env: Env):
+    """The configuration's reference module (``bench/reference/__init__.py``
+    states what it exposes)."""
+    return load_module(env.reference, f"bench_reference_{Path(env.reference).stem}")
 
 
 def group_layers(cfg) -> dict[str, list[int]]:

@@ -28,7 +28,7 @@ from jax.profiler import TraceAnnotation
 from bench import generate as gen
 from bench import weights
 from bench.apps.common import (DTYPES, Env, Outcome, TraceSlice, group_layers, model_config,
-                               peak_bytes)
+                               peak_bytes, reference)
 from bench.measure import percentile
 
 # Host spans, in the order an idle gap on the device is given to them.
@@ -226,13 +226,11 @@ def mean_logit_gap(env: Env, prompts, served, precision: str = "reference") -> f
     """Mean gap by which a served token's reference logit lies below the
     reference's best at its position, over the sampled requests.  (The widest
     gap is not compared: it does not separate the program from the control,
-    PERF.md section 2.)
+    PERF.md section 2.)  The reference is the configuration's own.
 
     With ``precision="control"`` the lower-precision control stands in for the
     program: it reads the same rows and its own first choice is judged.
     """
-    from bench.reference.dense import Dense
-
     if not served:
         return math.inf
     rows, tokens = sample_sequences(env, prompts, served)
@@ -240,8 +238,9 @@ def mean_logit_gap(env: Env, prompts, served, precision: str = "reference") -> f
     read = slice(PL - 1, rows.shape[1])
     key = gen.jax_key(env.seed)
     wdt = DTYPES[env.model["param_dtype"]]           # the weights as served
-    ref = Dense(env.model, weight_dtype=wdt)
-    ctl = Dense(env.model, weight_dtype=wdt, precision="control") if precision == "control" else None
+    make = reference(env).Reference
+    ref = make(env.model, weight_dtype=wdt, precision="reference")
+    ctl = make(env.model, weight_dtype=wdt, precision="control") if precision == "control" else None
     gaps = []
     for r in range(rows.shape[0]):                 # one request at a time: it fits
         lg = ref.forward(key, rows[r:r + 1], read)[0]

@@ -28,7 +28,8 @@ from jax.profiler import TraceAnnotation
 
 from bench import generate as gen
 from bench import weights
-from bench.apps.common import Env, Outcome, TraceSlice, group_layers, model_config, peak_bytes
+from bench.apps.common import (Env, Outcome, TraceSlice, group_layers, model_config, peak_bytes,
+                               reference)
 
 SPANS = ("next_batch", "step")
 FIRST_STEPS = 3
@@ -186,13 +187,11 @@ def reference_readings(env: Env, losses, grad1, change3) -> dict[str, float]:
 
 
 def _reference(env: Env, precision: str = "reference", rows: int | None = None) -> dict:
-    from bench.reference.hybrid import first_steps
-
     mix = env.mix
     batches = [gen.zipf_tokens(env.seed, i, mix["batch"], mix["seq"],
                                env.model["vocab_size"])[:rows] for i in range(FIRST_STEPS)]
-    return first_steps(env.model, gen.jax_key(env.seed), batches, mix["optimizer"], env.devices,
-                       precision)
+    return reference(env).first_steps(env.model, gen.jax_key(env.seed), batches,
+                                      mix["optimizer"], env.devices, precision)
 
 
 def control_reading(env: Env, outcome: Outcome) -> dict[str, dict[str, float]]:

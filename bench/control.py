@@ -38,13 +38,14 @@ def main(argv=None) -> int:
     spec = load_json(ROOT / "BENCHMARK.json")
     cell, config = find_cell(spec, args.workload)
     devices, _ = chips(cell["chips"])
-    model = load_json(ROOT / config["file"])["model"]
+    conf = load_json(ROOT / config["file"])
     mix = load_json(ROOT / "bench" / "traffic" / f"{cell['traffic']}.json")
     limits = load_json(ROOT / "bench" / "limits" / f"{args.workload}.json")
     app = importlib.import_module(f"bench.apps.{mix['app']}")
     first, last = (int(x) for x in args.seeds.split("-"))
     for i, seed in enumerate(range(first, last + 1)):
-        env = Env(cell=args.workload, model=model, mix=mix, limits=limits, seed=seed,
+        env = Env(cell=args.workload, model=conf["model"], reference=str(ROOT / conf["reference"]),
+                  mix=mix, limits=limits, seed=seed,
                   seconds=args.seconds, trace=False, devices=devices,
                   t_process=time.perf_counter(), out_dir=str(ROOT / "bench" / "out"))
         res = app.run(env)

@@ -10,6 +10,8 @@ weights come from ``bench.weights`` and the run's key, as the program's did.
 int8: every weight matrix rounded to int8 steps with one scale per output
 channel, and the activation entering each matrix product rounded to int8
 steps with one scale per row (its last axis is the one contracted).
+
+``Reference`` is the entry point that ``bench/reference/__init__.py`` states.
 """
 
 from __future__ import annotations
@@ -124,3 +126,6 @@ class Dense:
         for i in range(self.m["num_layers"]):
             x = self._layer(key, x, jnp.int32(i))
         return self._logits(key, x[:, read])
+
+
+Reference = Dense

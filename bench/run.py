@@ -3,9 +3,10 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name from ``BENCHMARK.json``: the cell names its
-configuration (``configs[].file``) and its traffic mix
-(``bench/traffic/<traffic>.json``, whose ``app`` key names the app module
-``bench/apps/<app>.py``); its correctness limits are
+configuration (``configs[].file``, whose ``model`` section the program runs
+and whose ``reference`` key is the path of its plain reference) and its
+traffic mix (``bench/traffic/<traffic>.json``, whose ``app`` key names the
+app module ``bench/apps/<app>.py``); its correctness limits are
 ``bench/limits/<cell>.json``; each per-layer metric is read by
 ``bench/metrics/<metric>.py``.  A run needs the chips its cell asks for:
 with none it exits non-zero and prints no result.
@@ -19,7 +20,6 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import importlib  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -68,11 +68,9 @@ def chips(n: int) -> tuple[list, dict]:
 
 
 def metric_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from bench.apps.common import load_module
+
+    return load_module(BENCH / "metrics" / f"{name}.py", f"bench_metric_{name}").read
 
 
 def cell_metrics(spec: dict, cell: str) -> tuple[list[dict], list[dict]]:
@@ -97,13 +95,15 @@ def execute(spec: dict, cell_name: str, seed: int, seconds: float, trace: bool, 
     cell, config = find_cell(spec, cell_name)
     if devices is None:
         devices, peaks = chips(cell["chips"])
-    model = model or load_json(ROOT / config["file"])["model"]
+    conf = load_json(ROOT / config["file"])
+    model = model or conf["model"]
     mix = mix or load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
     limits = limits or load_json(BENCH / "limits" / f"{cell_name}.json")
     out_dir = BENCH / "out" / cell_name / str(os.getpid())
     out_dir.mkdir(parents=True, exist_ok=True)
     app = importlib.import_module(f"bench.apps.{mix['app']}")
-    env = Env(cell=cell_name, model=model, mix=mix, limits=limits, seed=seed,
+    env = Env(cell=cell_name, model=model, reference=str(ROOT / conf["reference"]),
+              mix=mix, limits=limits, seed=seed,
               seconds=seconds, trace=trace, devices=devices, t_process=t_process,
               out_dir=str(out_dir), log=log)
     res = app.run(env)

@@ -13,6 +13,16 @@ TINY = {"name": "tiny", "family": "dense", "num_layers": 2, "d_model": 64, "num_
         "rope_theta": 10000.0, "tie_embeddings": True,
         "param_dtype": "float32", "compute_dtype": "float32"}
 PEAKS = {"flops_per_s_bf16": 1e12, "hbm_bytes_per_s": 1e11}
+# latent attention and routed experts at the widths of
+# repro.configs.deepseek_v2_lite_16b.smoke_config, one leading dense layer
+TINY_MLA_MOE = {"name": "tiny-mla-moe", "family": "moe", "num_layers": 3, "d_model": 64,
+                "num_heads": 4, "num_kv_heads": 4, "head_dim": 16, "d_ff": 64,
+                "vocab_size": 256, "rope_theta": 10000.0,
+                "mla": {"kv_lora_rank": 32, "qk_rope_dim": 8, "qk_nope_dim": 16,
+                        "v_head_dim": 16},
+                "moe": {"num_experts": 8, "top_k": 2, "num_shared": 1, "expert_d_ff": 64,
+                        "first_dense": 1},
+                "moe_impl": "dense", "param_dtype": "float32", "compute_dtype": "float32"}
 
 
 def chat_mix(**kw):

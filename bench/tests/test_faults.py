@@ -5,6 +5,7 @@ import itertools
 
 import jax
 
+from bench import run
 from bench.apps import serve
 from bench.tests.helpers import TINY, chat_mix, run_serve
 
@@ -77,7 +78,8 @@ def test_control_reads_above_the_program():
     mix = chat_mix(check_requests=8, rate_per_s=4.0, new_tokens=32, max_batch_size=8)
     prog, ctl = [], []
     for seed in (21, 22, 23):
-        env = Env(cell="serve.phi4.chat", model=SMALL_BF16, mix=mix,
+        env = Env(cell="serve.phi4.chat", model=SMALL_BF16,
+                  reference=str(run.ROOT / "bench" / "reference" / "dense.py"), mix=mix,
                   limits={"mean_logit_gap": 1.0}, seed=seed, seconds=2.0,
                   trace=False, devices=jax.devices()[:1], t_process=0.0, out_dir="",
                   log=lambda *a: None)

@@ -12,7 +12,32 @@ import numpy as np
 
 from bench import run, weights
 from bench.generate import jax_key
-from bench.tests.helpers import TINY, chat_mix, run_serve
+from bench.tests.helpers import TINY, TINY_MLA_MOE, chat_mix, run_serve
+
+STUB_REFERENCE = '''
+"""A reference that records its calls and returns zero logits."""
+import json
+from pathlib import Path
+
+import jax.numpy as jnp
+
+CALLS = Path(__file__).with_suffix(".calls")
+
+
+def note(**kw):
+    with open(CALLS, "a") as f:
+        f.write(json.dumps(kw) + "\\n")
+
+
+class Reference:
+    def __init__(self, model, *, weight_dtype, precision):
+        self.vocab = model["vocab_size"]
+        note(call="init", model=model["name"], precision=precision)
+
+    def forward(self, key, tokens, read):
+        note(call="forward", rows=len(tokens))
+        return jnp.zeros((*tokens[:, read].shape, self.vocab))
+'''
 
 
 def test_result_line_keys():
@@ -51,6 +76,44 @@ def test_new_traffic_file_found_by_name(tmp_path, monkeypatch):
     assert line["attempted"] == 12 and line["correct"] is True
     # the e2e metric whose workloads name only the chat cell is not reported
     assert set(line["metrics"]) == {"setup_s"}
+
+
+def test_new_config_found_by_name(tmp_path, monkeypatch):
+    """A configuration of another architecture (latent attention, routed
+    experts) enters by new files and entries alone: its file, its reference,
+    a traffic mix and limits."""
+    for sub in ("traffic", "limits", "configs", "reference"):
+        (tmp_path / sub).mkdir()
+    mix = chat_mix()
+    (tmp_path / "traffic" / "tiny_chat.json").write_text(json.dumps(mix))
+    (tmp_path / "limits" / "serve.tiny.mla.json").write_text(json.dumps({"mean_logit_gap": 1e-4}))
+    stub = tmp_path / "reference" / "stub.py"
+    stub.write_text(STUB_REFERENCE)
+    conf = tmp_path / "configs" / "tiny-mla-moe.json"
+    conf.write_text(json.dumps({"reference": str(stub), "model": TINY_MLA_MOE}))
+    monkeypatch.setattr(run, "BENCH", tmp_path)
+    drawn = set()
+    leaf = weights.leaf
+
+    def recorded(key, name, layer, shape, dtype):
+        drawn.add(name)
+        return leaf(key, name, layer, shape, dtype)
+
+    monkeypatch.setattr(weights, "leaf", recorded)
+    spec = json.loads((run.ROOT / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "tiny-mla-moe", "source": "-", "file": str(conf),
+                            "reduced": [], "why": "-"})
+    spec["workloads"].append({"name": "serve.tiny.mla", "config": "tiny-mla-moe",
+                              "traffic": "tiny_chat", "chips": 1, "why": "test"})
+    line = run.execute(spec, "serve.tiny.mla", 2**33 + 41, 2.0, False,
+                       devices=jax.devices()[:1], peaks={}, log=lambda *a: None)
+    assert line["correct"] is True and line["attempted"] == 24 and line["failed"] == 0
+    assert {"attn/w_q", "attn/w_dkv", "attn/w_uk", "attn/w_uv", "attn/w_o", "moe/router",
+            "moe/w_gate", "moe/w_up", "moe/w_down", "moe/shared/w_gate", "moe/shared/w_up",
+            "moe/shared/w_down", "mlp/w_gate"} <= drawn
+    calls = [json.loads(x) for x in stub.with_suffix(".calls").read_text().splitlines()]
+    assert calls[0] == {"call": "init", "model": "tiny-mla-moe", "precision": "reference"}
+    assert calls[1:] == [{"call": "forward", "rows": 1}] * mix["check_requests"]
 
 
 def test_no_chip_no_result():

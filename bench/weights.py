@@ -4,7 +4,9 @@ Each leaf of a layer is a function of (seed, leaf name, layer index) alone:
 ``leaf("attn/w_q", 7, ...)`` gives layer 7's query projection whether it is
 drawn alone (the reference, one layer at a time) or as one row of a stacked
 layer group (the program's parameter tree, drawn in one jitted call).  Matrix
-entries are uniform with the fan-in standard deviation; the arithmetic after
+entries are uniform with the fan-in standard deviation (the fan-in is axis 0
+of a projection, axis 1 of a routed-expert stack ``moe/*`` of shape
+``(experts, in, out)``); every leaf named ``scale`` is ones.  The arithmetic after
 the random bits is one exact affine map and one rounding multiply, so no
 compiler fusion can change a bit.
 """
@@ -21,15 +23,19 @@ import numpy as np
 
 ONES = {"scale", "norm_scale", "beta_attn", "beta_ssm", "d_skip"}
 ZEROS = {"conv_b"}
-FAN_IN_FIRST = {"w_q", "w_k", "w_v", "w_in", "w_gate", "w_up", "w_down", "w_out"}
+FAN_IN_FIRST = {"w_q", "w_k", "w_v", "w_in", "w_gate", "w_up", "w_down", "w_out",
+                "w_dkv", "w_uk", "w_uv", "router"}
 
 
 def _std(name: str, shape: tuple[int, ...]) -> float:
-    last = name.split("/")[-1]
+    path = name.split("/")
+    last = path[-1]
     if last == "embed":
         return 0.02
     if last == "unembed":
         return shape[1] ** -0.5
+    if path[0] == "moe" and len(shape) == 3:
+        return shape[1] ** -0.5               # (E, in, out): one matrix per expert
     if last in FAN_IN_FIRST:
         return shape[0] ** -0.5
     if last == "w_o":                         # (H, hd, d)
