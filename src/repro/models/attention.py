@@ -23,7 +23,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, normal_init
+from repro.models.layers import (
+    apply_norm,
+    apply_rope,
+    init_norm,
+    normal_init,
+    yarn_softmax_factor,
+)
 
 Params = dict[str, Any]
 
@@ -56,6 +62,7 @@ def init_attention(cfg, key) -> Params:
                 ks[4], (H, m.v_head_dim, d), (H * m.v_head_dim) ** -0.5,
                 cfg.param_dtype,
             ),
+            "kv_norm": init_norm(cfg, m.kv_lora_rank),
         }
         return p
     p = {
@@ -410,8 +417,14 @@ def apply_mla(
 ) -> tuple[jax.Array, Params | None]:
     """DeepSeek-V2 MLA: low-rank compressed KV with decoupled RoPE keys.
 
-    Decode uses the *absorbed* formulation: scores are computed directly in
-    the latent space, so the cache is only (kv_lora_rank + rope_dim) wide.
+    The latent ``c`` is RMS-normed before its up-projections and the cache
+    holds it normed; the rotary is YaRN-scaled when ``cfg.yarn`` says so.
+    Without a cache, and in a prefill into an empty cache, keys and values
+    are expanded per head and attend causally over the prompt's own
+    positions; the prefill then writes ``c`` and ``k_rope`` into the cache
+    with one slice.  Decode uses the *absorbed* formulation: scores are
+    computed directly in the latent space, so the cache is only
+    (kv_lora_rank + rope_dim) wide.
     """
     from repro.models.common import shard_hint
 
@@ -420,20 +433,21 @@ def apply_mla(
     H = cfg.num_heads
     B, S, _ = x.shape
     x = x.astype(ct)
-    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5
+    scale = (m.qk_nope_dim + m.qk_rope_dim) ** -0.5 * yarn_softmax_factor(cfg.yarn)
 
     q = jnp.einsum("bsd,dhk->bshk", x, p["w_q"].astype(ct))
     if ctx is not None:
         q = shard_hint(q, ctx, ("dp", None, "tp", None))
     q_nope, q_rope = jnp.split(q, [m.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, cfg.yarn)
 
     ckr = x @ p["w_dkv"].astype(ct)  # (B, S, r + rope)
     c, k_rope = jnp.split(ckr, [m.kv_lora_rank], axis=-1)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    c = apply_norm(cfg, p["kv_norm"], c)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta, cfg.yarn)[:, :, 0]
 
-    if cache is None:
-        # train/prefill: expand keys/values per head (standard formulation)
+    if cache is None or _fills_empty_cache(ctx):
+        # expand keys/values per head (standard formulation)
         k_nope = jnp.einsum("bsr,rhk->bshk", c, p["w_uk"].astype(ct))
         vfull = jnp.einsum("bsr,rhk->bshk", c, p["w_uv"].astype(ct))
         k = jnp.concatenate(
@@ -442,12 +456,19 @@ def apply_mla(
         )
         qf = jnp.concatenate([q_nope, q_rope], axis=-1)
         out = chunked_attention(
-            qf[:, :, :, None, :].reshape(B, S, H, 1, -1),
-            k, vfull, causal=True, chunk=cfg.attention_chunk, scale=scale,
+            qf[:, :, :, None, :], k, vfull,
+            causal=True, chunk=cfg.attention_chunk, scale=scale,
         ).reshape(B, S, H, m.v_head_dim)
         new_cache = None
+        if cache is not None:
+            with jax.named_scope("kv_write"):
+                new_cache = {
+                    "c": jax.lax.dynamic_update_slice(cache["c"], c, (0, 0, 0)),
+                    "k_rope": jax.lax.dynamic_update_slice(cache["k_rope"], k_rope, (0, 0, 0)),
+                    "length": jnp.full_like(cache["length"], S),
+                }
     else:
-        # decode: absorbed formulation against the latent cache
+        # absorbed formulation against the latent cache
         length = cache["length"]
         size = cache["c"].shape[1]
         with jax.named_scope("kv_write"):
@@ -457,24 +478,53 @@ def apply_mla(
             kr_all = cache["k_rope"].at[bidx, write_pos].set(k_rope)
         new_len = length + S
         new_cache = {"c": c_all, "k_rope": kr_all, "length": new_len}
-
-        q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"].astype(ct))
-        # latent "keys" = [c, k_rope]; latent "queries" = [q_abs, q_rope]
-        k_lat = jnp.concatenate([c_all, kr_all], axis=-1)  # (B, T, r+rope)
-        q_lat = jnp.concatenate([q_abs, q_rope], axis=-1)  # (B, S, H, r+rope)
-        out_lat = chunked_attention(
-            q_lat[:, :, None, :, :],       # (B,S,1 kv-head,H groups,dim)
-            k_lat[:, :, None, :],          # single shared "kv head"
-            c_all[:, :, None, :],          # attend into latent values
-            causal=True, kv_len=new_len, q_offset=length,
-            chunk=cfg.attention_chunk, scale=scale,
-        ).reshape(B, S, H, m.kv_lora_rank)
-        out = jnp.einsum("bshr,rhk->bshk", out_lat, p["w_uv"].astype(ct))
+        with jax.named_scope("absorbed"):
+            out = _absorbed_attention(
+                cfg, p, q_nope, q_rope, c_all, kr_all, length, new_len, scale
+            )
 
     y = jnp.einsum("bshk,hkd->bsd", out, p["w_o"].astype(ct))
     if ctx is not None:
         y = shard_hint(y, ctx, ("dp", None, None))
     return y, new_cache
+
+
+def _absorbed_attention(cfg, p, q_nope, q_rope, c_all, kr_all, length, new_len, scale):
+    """Queries absorbed into the latent space attend over the cached ``c``
+    (scores against ``[c, k_rope]``, values ``c``), then ``w_uv`` lifts the
+    result to each head.
+
+    One new position (decode) sums the two score terms rather than
+    concatenate the cache, which would copy it.  Several (a prefill under a
+    mesh, or into a cache that holds a prefix) go through the tiled core at
+    ``attention_chunk``: a whole ``(B, S, H, T)`` score tensor would not fit.
+    """
+    ct = cfg.compute_dtype
+    B, S, H, _ = q_nope.shape
+    T, r = c_all.shape[1], c_all.shape[2]
+    q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"].astype(ct))
+    if S > 1:
+        out_lat = chunked_attention(
+            jnp.concatenate([q_abs, q_rope], axis=-1)[:, :, None],  # one kv head, H groups
+            jnp.concatenate([c_all, kr_all], axis=-1)[:, :, None],
+            c_all[:, :, None],
+            causal=True, kv_len=new_len, q_offset=length,
+            chunk=cfg.attention_chunk, scale=scale,
+        ).reshape(B, S, H, r)
+    else:
+        s = jnp.einsum("bshr,btr->bsht", q_abs, c_all, preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("bshp,btp->bsht", q_rope, kr_all,
+                           preferred_element_type=jnp.float32)
+        q_pos = length[:, None] + jnp.arange(S)                  # (B, S)
+        k_pos = jnp.arange(T)
+        mask = (k_pos[None, None, :] < new_len[:, None, None]) & (
+            k_pos[None, None, :] <= q_pos[:, :, None])           # (B, S, T)
+        s = jnp.where(mask[:, :, None, :], s * scale, NEG_INF)
+        w = jax.nn.softmax(s, axis=-1)
+        out_lat = jnp.einsum(
+            "bsht,btr->bshr", w.astype(ct), c_all, preferred_element_type=jnp.float32
+        ).astype(ct)
+    return jnp.einsum("bshr,rhk->bshk", out_lat, p["w_uv"].astype(ct))
 
 
 def init_mla_cache(cfg, batch: int, max_len: int) -> Params:

@@ -44,13 +44,25 @@ def shard_hint(x, ctx, dims: tuple) -> Any:
 
 @dataclass
 class MoEConfig:
-    num_experts: int = 0           # routed experts
+    num_experts: int = 0           # routed experts (the router's width)
     top_k: int = 0
     num_shared: int = 0            # shared (always-on) experts
     expert_d_ff: int = 0           # per-expert hidden
     first_dense: int = 0           # leading dense layers before MoE starts
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    norm_topk_prob: bool = True    # renormalise the top-k weights to sum to 1
+    routed_scaling_factor: float = 1.0  # applied to the routed experts' weights
+    # The experts this layer holds: ``num_held`` of them from ``first_held``
+    # (0 = all ``num_experts``), as one chip of an expert-parallel layer holds
+    # its block.  The router still scores every expert; the layer computes the
+    # part of the result that its own experts give.
+    first_held: int = 0
+    num_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_held or self.num_experts
 
 
 @dataclass
@@ -80,6 +92,23 @@ class MLAConfig:
 
 
 @dataclass
+class YarnConfig:
+    """YaRN rotary scaling as DeepSeek-V2 publishes it (``rope_scaling`` of
+    type ``yarn``): frequencies between the ``beta_fast`` and ``beta_slow``
+    rotation counts (over ``original_max_position`` positions) ramp from the
+    original to the original divided by ``factor``; cos and sin are scaled
+    by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)`` and the
+    softmax scale by ``mscale(factor, mscale_all_dim) ** 2``."""
+
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass
 class ModelConfig:
     name: str
     family: str                    # dense | moe | ssm | hybrid | audio | vlm
@@ -100,6 +129,7 @@ class ModelConfig:
     moe: MoEConfig | None = None
     ssm: SSMConfig | None = None
     mla: MLAConfig | None = None
+    yarn: YarnConfig | None = None    # rotary scaling of latent attention
 
     # hybrid (hymba): sliding window for local attention layers; indices of
     # layers using global (full) attention
@@ -128,7 +158,7 @@ class ModelConfig:
     # scanned cache ys-buffer otherwise round-trips the full stacked cache
     # every iteration (§Perf granite decode iteration 2).
     scan_layers: bool = True
-    moe_impl: str = "ep"                # ep (shard_map all-to-all) | dense
+    moe_impl: str = "ep"                # training: ep (shard_map all-to-all) | dense
     remat: str = "none"                 # none | dots | full
     num_microbatches: int = 1
     logits_chunk: int = 0               # 0 = single logits matmul
@@ -136,6 +166,8 @@ class ModelConfig:
     def __post_init__(self) -> None:
         if self.head_dim == 0:
             self.head_dim = self.d_model // self.num_heads
+        if self.yarn is not None and self.mla is None:
+            raise ValueError("yarn rotary scaling is implemented for latent attention only")
 
     @property
     def is_encdec(self) -> bool:
@@ -190,7 +222,7 @@ class ModelConfig:
             mo = self.moe
             expert = mlp_mult * d * mo.expert_d_ff
             router = d * mo.num_experts
-            moe_total = mo.num_experts * expert + mo.num_shared * expert + router
+            moe_total = mo.held * expert + mo.num_shared * expert + router
             moe_active = mo.top_k * expert + mo.num_shared * expert + router
             n_moe_layers = self.num_layers - mo.first_dense
             per_layer_total = attn + moe_total

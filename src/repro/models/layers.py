@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -42,17 +43,57 @@ def apply_norm(cfg, p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 # -- rotary embeddings ----------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def yarn_softmax_factor(yarn) -> float:
+    """What YaRN multiplies the softmax scale by (1 without it)."""
+    return 1.0 if yarn is None else _yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+
+
+def yarn_rotary_scale(yarn) -> float:
+    """What YaRN multiplies cos and sin by (1 without it)."""
+    if yarn is None:
+        return 1.0
+    return _yarn_mscale(yarn.factor, yarn.mscale) / _yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+
+
+def _yarn_ramp(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """0 for the pairs that keep their frequency (more than ``beta_fast``
+    rotations over the original context), 1 for those divided by the factor
+    (fewer than ``beta_slow``), linear between."""
+
+    def dim(rotations: float) -> float:
+        turns = yarn.original_max_position / (rotations * 2 * math.pi)
+        return head_dim * math.log(turns) / (2 * math.log(theta))
+
+    low = max(math.floor(dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim(yarn.beta_slow)), head_dim - 1)
+    if high == low:
+        high += 0.001
+    return np.clip((np.arange(head_dim // 2) - low) / (high - low), 0.0, 1.0)
+
+
+def rope_frequencies(head_dim: int, theta: float, yarn=None) -> np.ndarray:
+    freqs = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    if yarn is None:
+        return freqs
+    ramp = _yarn_ramp(head_dim, theta, yarn)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, yarn=None) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).  ``yarn``
+    (a ``YarnConfig``) rescales the frequencies and cos/sin."""
     hd = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(hd, theta), jnp.float32)
+    freqs = jnp.asarray(rope_frequencies(hd, theta, yarn), jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None:
+        gain = yarn_rotary_scale(yarn)
+        cos, sin = cos * gain, sin * gain
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

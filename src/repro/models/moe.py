@@ -1,17 +1,28 @@
-"""Mixture-of-Experts: expert-parallel all-to-all dispatch (shard_map).
+"""Mixture-of-Experts: a router over every expert, and the experts a layer holds.
 
-Two implementations share parameters:
+A layer holds ``moe.held`` experts from ``moe.first_held`` (all of them
+unless the configuration says otherwise, as one chip of an expert-parallel
+layer holds its block).  The router scores all ``num_experts``; the layer
+computes the part of the result that its own experts give, for the tokens
+routed to them, plus the shared experts for every token.
 
-* ``ep`` -- the production path.  Tokens are sequence-sharded over the
+Three implementations share parameters:
+
+* ``grouped`` -- the served path (prefill and decode) whenever the layer
+  makes no exchange (at most one device).  Dropless: the (token, held
+  expert) pairs are sorted by expert and cut into blocks of ``rows`` rows,
+  each expert's pairs padded to at most one block, and a loop over the
+  blocks in use runs each block through its expert.  Work and weight reads
+  follow the routed pairs, not tokens x held experts.
+* ``ep`` -- training over a mesh.  Tokens are sequence-sharded over the
   ``model`` mesh axis; each rank routes its local tokens, packs per-expert
   capacity buffers, and exchanges them with ``jax.lax.all_to_all`` over the
   expert-parallel axis (experts live sharded over ``model``).  GShard-style
   capacity with token dropping; a sort-based dispatch (gather/scatter, no
   one-hot dispatch einsum, so dispatch costs O(N k d) not O(N E C d)).
-* ``dense`` -- reference path (and decode path): computes every expert on
-  every token; exact, trivially correct, used for smoke tests, 1-device
-  runs, and decode steps where token counts are tiny and most experts are
-  hit anyway.
+* ``dense`` -- reference path: computes every held expert on every token;
+  exact, trivially correct; the tests' oracle, training without a mesh, and
+  serving over more than one device.
 
 The router aux (load-balance) loss follows Switch: ``E * sum_e f_e * P_e``.
 """
@@ -32,14 +43,14 @@ Params = dict[str, Any]
 
 def init_moe(cfg, key) -> Params:
     mo = cfg.moe
-    d, f, E = cfg.d_model, mo.expert_d_ff, mo.num_experts
+    d, f, E, held = cfg.d_model, mo.expert_d_ff, mo.num_experts, mo.held
     ks = jax.random.split(key, 7)
     std, std_out = d**-0.5, f**-0.5
     p = {
         "router": normal_init(ks[0], (d, E), std, cfg.param_dtype),
-        "w_gate": normal_init(ks[1], (E, d, f), std, cfg.param_dtype),
-        "w_up": normal_init(ks[2], (E, d, f), std, cfg.param_dtype),
-        "w_down": normal_init(ks[3], (E, f, d), std_out, cfg.param_dtype),
+        "w_gate": normal_init(ks[1], (held, d, f), std, cfg.param_dtype),
+        "w_up": normal_init(ks[2], (held, d, f), std, cfg.param_dtype),
+        "w_down": normal_init(ks[3], (held, f, d), std_out, cfg.param_dtype),
     }
     if mo.num_shared > 0:
         fs = mo.num_shared * f
@@ -52,13 +63,24 @@ def init_moe(cfg, key) -> Params:
 
 
 def _router(cfg, p, x2d):
-    """x2d: (N, d) -> top-k ids/weights and aux loss terms (fp32 router)."""
+    """x2d: (N, d) -> softmax scores over every expert, the top-k expert ids
+    and their weights (fp32 router): the scores themselves, or renormalised
+    to sum to 1 (``norm_topk_prob``), times ``routed_scaling_factor``."""
     mo = cfg.moe
     logits = x2d.astype(jnp.float32) @ p["router"].astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     top_w, top_i = jax.lax.top_k(probs, mo.top_k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    return probs, top_i, top_w
+    if mo.norm_topk_prob:
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    return probs, top_i, top_w * mo.routed_scaling_factor
+
+
+def _held_index(cfg, top_i):
+    """Each routed pair's index among the held experts, ``held`` where the
+    expert is held elsewhere."""
+    mo = cfg.moe
+    local = top_i - mo.first_held
+    return jnp.where((local >= 0) & (local < mo.held), local, mo.held)
 
 
 def _aux_loss(cfg, probs, top_i):
@@ -87,7 +109,7 @@ def _shared_ffn(cfg, p, x):
     return (jax.nn.silu(g) * u) @ sp["w_down"].astype(ct)
 
 
-# -- dense reference (and decode) path ----------------------------------------
+# -- dense reference path -------------------------------------------------------
 
 def apply_moe_dense(cfg, p: Params, x: jax.Array) -> tuple[jax.Array, jax.Array]:
     mo = cfg.moe
@@ -98,17 +120,92 @@ def apply_moe_dense(cfg, p: Params, x: jax.Array) -> tuple[jax.Array, jax.Array]
     # combine weights over all experts: (N, E)
     combine = jnp.zeros_like(probs)
     nidx = jnp.arange(x2.shape[0])[:, None]
-    combine = combine.at[nidx, top_i].add(top_w)
-    # all experts on all tokens (exact; used for tests + decode)
+    combine = combine.at[nidx, top_i].add(top_w)[:, mo.first_held:mo.first_held + mo.held]
+    # all held experts on all tokens (exact; the tests' oracle)
     y_all = _expert_ffn(
         cfg, p["w_gate"], p["w_up"], p["w_down"],
-        jnp.broadcast_to(x2[None], (mo.num_experts, *x2.shape)),
-    )  # (E, N, d)
+        jnp.broadcast_to(x2[None], (mo.held, *x2.shape)),
+    )  # (held, N, d)
     y = jnp.einsum("end,ne->nd", y_all, combine.astype(ct))
     if mo.num_shared > 0:
         y = y + _shared_ffn(cfg, p, x2)
     aux = _aux_loss(cfg, probs, top_i)
     return y.reshape(B, S, d), aux
+
+
+# -- grouped (served) path --------------------------------------------------------
+
+def block_rows(n_tokens: int, cfg) -> int:
+    """Rows per block of the grouped path: twice the pairs an expert expects
+    (``n_tokens * top_k / num_experts``), a power of two in [8, 512]."""
+    mo = cfg.moe
+    want = max(1, 2 * n_tokens * mo.top_k // mo.num_experts)
+    return min(512, max(8, 1 << (want - 1).bit_length()))
+
+
+def expert_blocks(local: jax.Array, held: int, n_tokens: int, top_k: int, rows: int):
+    """Cut the routed pairs on held experts into blocks of ``rows``.
+
+    ``local``: (P,) held-expert index of each pair (``held`` = held
+    elsewhere).  Pairs are sorted by expert and each expert's pairs padded to
+    a whole number of blocks, so at most one block per expert is part
+    padding.  Returns ``(block_expert (NB,), block_pairs (NB, rows),
+    n_blocks)``: the expert of each block, the pair index of each row (``P``
+    for padding), and how many blocks are in use; ``NB`` is the static most.
+    """
+    P = local.shape[0]
+    nb = -(-n_tokens * min(top_k, held) // rows) + held
+    counts = jnp.bincount(local, length=held + 1)[:held]
+    blocks = (counts + rows - 1) // rows
+    ends = jnp.cumsum(blocks)
+    block_expert = jnp.minimum(jnp.searchsorted(ends, jnp.arange(nb), side="right"), held - 1)
+    # row r of block b holds the expert's pair number (b - its first block) * rows + r
+    within = ((jnp.arange(nb) - (ends - blocks)[block_expert])[:, None] * rows
+              + jnp.arange(rows)[None, :])
+    valid = (within < counts[block_expert][:, None]) & (jnp.arange(nb) < ends[-1])[:, None]
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)    # by expert, held ones first
+    at = (jnp.cumsum(counts) - counts)[block_expert][:, None] + within
+    pairs = jnp.where(valid, order[jnp.minimum(at, P - 1)], P)
+    return block_expert, pairs, ends[-1]
+
+
+def apply_moe_grouped(cfg, p: Params, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Dropless held-expert layer with no exchange (the served path)."""
+    mo = cfg.moe
+    ct = cfg.compute_dtype
+    B, S, d = x.shape
+    x2 = x.reshape(-1, d).astype(ct)
+    N, k = x2.shape[0], mo.top_k
+    with jax.named_scope("router"):
+        _, top_i, top_w = _router(cfg, p, x2)
+    rows = block_rows(N, cfg)
+    with jax.named_scope("dispatch"):
+        block_expert, pairs, n_blocks = expert_blocks(
+            _held_index(cfg, top_i).reshape(-1), mo.held, N, k, rows)
+        # pair N * k (padding) reads a zero row, weighs 0 and lands out of range
+        tok = jnp.append(jnp.arange(N * k, dtype=jnp.int32) // k, N)
+        wts = jnp.append(top_w.reshape(-1), 0.0)
+    w_gate, w_up, w_down = (p[n].astype(ct) for n in ("w_gate", "w_up", "w_down"))
+
+    def block(b, y):
+        with jax.named_scope("dispatch"):
+            pair = pairs[b]
+            t = tok[pair]
+            xb = x2.at[t].get(mode="fill", fill_value=0)
+        with jax.named_scope("experts"):
+            e = block_expert[b]
+            h = jax.nn.silu(xb @ w_gate[e]) * (xb @ w_up[e])
+            out = h @ w_down[e]
+        with jax.named_scope("combine"):
+            return y.at[t].add(out.astype(jnp.float32) * wts[pair][:, None], mode="drop")
+
+    y = jax.lax.fori_loop(0, n_blocks, block, jnp.zeros((N, d), jnp.float32))
+    with jax.named_scope("combine"):
+        y = y.astype(ct)
+    if mo.num_shared > 0:
+        with jax.named_scope("shared"):
+            y = y + _shared_ffn(cfg, p, x2)
+    return y.reshape(B, S, d), jnp.zeros((), jnp.float32)
 
 
 # -- expert-parallel path -------------------------------------------------------
@@ -156,17 +253,17 @@ def apply_moe_ep(
     mesh: jax.sharding.Mesh,
     dp_axes: tuple[str, ...] = ("data",),
     ep_axis: str = "model",
-    seq_shard: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Expert-parallel MoE via shard_map + all_to_all over ``ep_axis``."""
     mo = cfg.moe
     ct = cfg.compute_dtype
     ep = mesh.shape[ep_axis]
     E = mo.num_experts
+    assert mo.held == E, "the expert-parallel path shards every expert over the mesh"
     assert E % ep == 0, f"experts {E} must divide EP axis {ep}"
     B, S, d = x.shape
 
-    seq_spec = ep_axis if (seq_shard and S % ep == 0 and S >= ep) else None
+    seq_spec = ep_axis if (S % ep == 0 and S >= ep) else None
     x_spec = P(dp_axes, seq_spec, None)
     w_spec = P(ep_axis, None, None)
     all_axes = tuple(mesh.axis_names)
@@ -216,11 +313,13 @@ def apply_moe(
     mesh: jax.sharding.Mesh | None = None,
     dp_axes: tuple[str, ...] = ("data",),
     ep_axis: str = "model",
-    decode: bool = False,
+    serving: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    if cfg.moe_impl == "ep" and mesh is not None and not decode:
+    """``serving``: a prefill or decode call, which takes no gradient."""
+    if serving and (mesh is None or mesh.size == 1):
+        return apply_moe_grouped(cfg, p, x)
+    if cfg.moe_impl == "ep" and mesh is not None and not serving:
         return apply_moe_ep(
             cfg, p, x, mesh=mesh, dp_axes=dp_axes, ep_axis=ep_axis,
-            seq_shard=not decode,
         )
     return apply_moe_dense(cfg, p, x)

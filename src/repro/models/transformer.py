@@ -92,18 +92,8 @@ def _init_layer(cfg: ModelConfig, group: LayerGroup, key) -> Params:
     elif group.kind == "moe":
         p["moe"] = moe_mod.init_moe(cfg, ks[1])
     else:
-        f = cfg.d_ff
-        if cfg.family == "moe":  # leading dense layer of an MoE arch
-            f = _dense_ff_for_moe(cfg)
-        p["mlp"] = init_mlp(cfg, ks[1], cfg.d_model, f)
+        p["mlp"] = init_mlp(cfg, ks[1], cfg.d_model, cfg.d_ff)
     return p
-
-
-def _dense_ff_for_moe(cfg: ModelConfig) -> int:
-    # Active-FLOP-matched hidden for the leading dense layer(s):
-    # (top_k + shared) * expert_d_ff, the standard DeepSeek-style choice.
-    mo = cfg.moe
-    return (mo.top_k + mo.num_shared) * mo.expert_d_ff
 
 
 def init_params(cfg: ModelConfig, key) -> Params:
@@ -124,9 +114,14 @@ def init_params(cfg: ModelConfig, key) -> Params:
 class RunCtx:
     """Per-call distribution context (mesh for EP, decode flags).
 
+    ``decode`` marks a call that takes no gradient (set by
+    :func:`decode_step`; a serving caller may set it for its prefill too);
+    every call with a cache is one.  Expert layers then take the served path.
+
     ``empty_cache`` is set by :func:`prefill` alone: the cache it fills is
     empty and the positions start at 0, so full-attention layers attend over
-    their own keys (the flash kernel) and write them with one slice.
+    their own keys (the flash kernel, or latent attention's expanded form)
+    and write them with one slice.
     """
 
     mesh: Any = None
@@ -202,7 +197,7 @@ def _apply_layer(
             y2, aux = moe_mod.apply_moe(
                 cfg, p["moe"], h2,
                 mesh=ctx.mesh, dp_axes=ctx.dp_axes, ep_axis=ctx.ep_axis,
-                decode=ctx.decode,
+                serving=ctx.decode or cache is not None,
             )
             return x + y2, a_new, aux
     with jax.named_scope("mlp"):

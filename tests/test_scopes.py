@@ -58,7 +58,7 @@ def test_the_scans_slices_are_loop(dense_decode):
     assert {ops[s] for s in slices} == {"loop"}
     # the cache write inside the layer is the layer's own
     kv = [name for name, _, op in _instructions(dense_decode) if op and "/attn/kv_write/" in op]
-    assert kv and {ops[k] for k in kv} == {"attn"}
+    assert kv and {ops[k] for k in kv} == {"attn/kv_write"}
     assert "embed" in ops.values()
 
 
@@ -70,8 +70,43 @@ def test_the_scans_slices_are_loop(dense_decode):
 ])
 def test_each_family_has_its_scopes(arch, expected):
     found = set(scopes.op_scopes(_compiled_decode(get_smoke_config(arch))).values())
-    assert expected <= found
-    assert found <= set(scopes.SCOPES) | {scopes.LOOP, scopes.OTHER}
+    assert expected <= {s.split("/")[0] for s in found}
+    parts = {f"{s}/{p}" for s, ps in scopes.PARTS.items() for p in ps}
+    assert found <= set(scopes.SCOPES) | parts | {scopes.LOOP, scopes.OTHER}
+
+
+def _compiled_prefill(cfg):
+    """``prefill`` of ``cfg`` compiled on the CPU from shapes alone."""
+    params = jax.eval_shape(lambda k: tx.init_params(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: tx.init_cache(cfg, B, PL + G + 1))
+    toks = jax.ShapeDtypeStruct((B, PL), jnp.int32)
+
+    def serve_prefill(p, t, c):
+        return tx.prefill(cfg, p, t, c)
+
+    return jax.jit(serve_prefill).lower(params, toks, cache).compile().as_text()
+
+
+MOE_PARTS = {"moe/router", "moe/dispatch", "moe/experts", "moe/combine", "moe/shared"}
+
+
+@pytest.mark.parametrize("call,expected", [
+    ("prefill", MOE_PARTS | {"attn/kv_write"}),
+    ("decode", MOE_PARTS | {"attn/kv_write", "attn/absorbed"}),
+])
+def test_latent_and_expert_parts(call, expected):
+    """The served calls of the latent-attention, routed-expert model map
+    their expert and latent ops to the parts of ``moe`` and ``attn``: the
+    routed experts' products to ``moe/experts``, the absorbed core's to
+    ``attn/absorbed``."""
+    cfg = get_smoke_config("deepseek-v2-lite-16b")
+    text = (_compiled_prefill if call == "prefill" else _compiled_decode)(cfg)
+    parts = scopes.op_scopes(text)
+    assert expected <= set(parts.values())
+    dots = {parts[name] for name, opcode, _ in _instructions(text) if opcode == "dot"}
+    assert "moe/experts" in dots and "moe/shared" in dots and "moe/router" in dots
+    if call == "decode":
+        assert "attn/absorbed" in dots
 
 
 def _strip_metadata(text: str) -> str:
@@ -88,7 +123,7 @@ def test_scopes_change_only_metadata(dense_decode, monkeypatch):
 
 
 @pytest.mark.parametrize("op_name,scope", [
-    ("jit(f)/while/body/closed_call/attn/kv_write/scatter", "attn"),
+    ("jit(f)/while/body/closed_call/attn/kv_write/scatter", "attn/kv_write"),
     ("jit(f)/while/body/checkpoint/mlp/dot_general", "mlp"),
     ("jit(f)/transpose(jvp(moe))/dot_general", "moe"),
     ("jit(f)/while/body/ssm/attn/add", "attn"),
@@ -102,6 +137,20 @@ def test_scopes_change_only_metadata(dense_decode, monkeypatch):
 ])
 def test_scope_of(op_name, scope):
     assert scopes.scope_of(op_name) == scope
+
+
+@pytest.mark.parametrize("op_name,part", [
+    ("jit(f)/while/body/attn/kv_write/scatter", "attn/kv_write"),
+    ("jit(f)/while/body/attn/absorbed/dot_general", "attn/absorbed"),
+    ("jit(f)/while/body/moe/while/body/experts/dot_general", "moe/experts"),
+    ("jit(f)/while/body/moe/while/body/combine/scatter-add", "moe/combine"),
+    ("jit(f)/while/body/moe/router/top_k", "moe/router"),
+    ("jit(f)/while/body/moe/add", "moe"),
+    ("jit(f)/while/body/mlp/experts/dot_general", "mlp"),
+    ("jit(f)/while/body/dynamic_slice", "loop"),
+])
+def test_scope_of_parts(op_name, part):
+    assert scopes.scope_of(op_name) == part
 
 
 def test_op_without_metadata_takes_its_fused_scope_or_is_other():
